@@ -38,6 +38,18 @@ def unpack_int4(packed: Array) -> Array:
     return out.reshape(*packed.shape[:-1], packed.shape[-1] * 2)
 
 
+def store_codes(codes: Array, bits: int) -> Array:
+    """uint8 codes in [0, 2**bits) -> serving storage: int4 packed two per
+    byte (uint8), or int8 offset by -128 (the k=256 storage convention)."""
+    if bits == 4:
+        return pack_int4(codes)
+    if bits == 8:
+        # c - 128 in two's complement is c with its top bit flipped
+        return jax.lax.bitcast_convert_type(codes.astype(jnp.uint8) ^ 0x80,
+                                            jnp.int8)
+    raise ValueError(f"serving bits must be 4 or 8, got {bits}")
+
+
 def packed_shape(shape, bits: int):
     """Storage shape for codes of ``shape`` at ``bits`` in {4, 8}."""
     if bits == 4:

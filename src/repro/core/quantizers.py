@@ -37,9 +37,8 @@ def kquantile_quantize(w: Array, model, k: int,
                        code_dtype=jnp.int32) -> Array:
     """Codes of the k-quantile quantizer:  c = floor(k * F(w)) in [0, k-1]."""
     u = model.cdf(w)
-    c = jnp.floor(u * k).astype(jnp.int32)
-    c = jnp.clip(c, 0, k - 1)
-    return c.astype(code_dtype)
+    # clip in float, then one cast: no int32 copy when code_dtype is uint8
+    return jnp.clip(jnp.floor(u * k), 0, k - 1).astype(code_dtype)
 
 
 def kquantile_dequantize(codes: Array, model, k: int,

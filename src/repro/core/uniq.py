@@ -324,16 +324,14 @@ class QuantizedTensor:
 
 def quantize_tensor(w: Array, bits: int, per_channel: bool = True,
                     stacked: bool = False) -> QuantizedTensor:
-    """Offline k-quantile quantization of a weight tensor for serving."""
+    """Offline k-quantile quantization of a weight tensor for serving.
+
+    Codes go straight to their uint8/int8 storage; no int32 copy of the
+    tensor is made."""
     from repro.core import packing
     model = fit_gaussian(w, _stats_axes(w, per_channel, stacked))
-    codes = Q.kquantile_quantize(w, model, 2 ** bits, code_dtype=jnp.int32)
-    if bits == 4:
-        stored = packing.pack_int4(codes)
-    elif bits == 8:
-        stored = (codes - 128).astype(jnp.int8)  # storage offset for k=256
-    else:
-        raise ValueError(f"serving bits must be 4 or 8, got {bits}")
+    codes = Q.kquantile_quantize(w, model, 2 ** bits, code_dtype=jnp.uint8)
+    stored = packing.store_codes(codes, bits)
     return QuantizedTensor(stored, model.mu.astype(jnp.float32),
                            model.sigma.astype(jnp.float32), bits,
                            tuple(w.shape))

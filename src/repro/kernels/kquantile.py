@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat as pc
 
 _SQRT2 = 1.4142135623730951
 _EPS = 1e-6
@@ -74,10 +73,10 @@ def _elementwise_call(kernel, x, mu, sigma, out_dtype, k, block_r, block_c,
         ],
         out_specs=pl.BlockSpec((1, block_r, block_c), lambda g, i, j: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((G, R, C), out_dtype),
-        compiler_params=pc.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
-        interpret=pc.interpret_mode(interpret),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(x, mu, sigma)
 
 

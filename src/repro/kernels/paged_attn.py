@@ -50,8 +50,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat as pc
-
 _SQRT2 = 1.4142135623730951
 _EPS = 1e-6
 NEG_INF = -1e30
@@ -69,27 +67,37 @@ def default_splits(n_pages: int) -> int:
     return min(DEFAULT_SPLITS, n_pages)
 
 
-def _dequant_page(codes, mu, sigma, bits: int, k: int):
-    """(page, KV, D') codes + (page, KV) stats -> (page, KV, D) f32."""
+def _dequant_parts(codes, mu, sigma, bits: int, k: int):
+    """(page, KV, Dc) codes + (page, KV) stats -> tuple of (page, KV, Dc)
+    f32 planes: one plane at kv8, the even- and odd-column planes at kv4.
+
+    The int4 nibbles are unpacked through int32 (Mosaic lowers no
+    uint8 -> f32 cast) and never interleaved back to (page, KV, D): an
+    interleave builds a trailing axis of 2, which the TPU tiles to 128
+    lanes — 64x the plane's VMEM.  ``pack_int4`` puts even columns in the
+    low nibble, so plane 0 holds columns 0, 2, 4, ... and plane 1 the odd
+    ones; the caller splits q and the V accumulator the same way.
+    """
+    c = codes.astype(jnp.int32)
     if bits == 4:
-        lo = (codes & 0x0F).astype(jnp.float32)
-        hi = ((codes >> 4) & 0x0F).astype(jnp.float32)
-        c = jnp.stack([lo, hi], axis=-1)
-        c = c.reshape(*codes.shape[:-1], codes.shape[-1] * 2)
+        planes = (c & 0x0F, (c >> 4) & 0x0F)
     else:
-        c = codes.astype(jnp.float32)
-        if k == 256:  # undo int8 storage offset
-            c = c + 128.0
-    centers = jnp.clip((c + 0.5) / k, _EPS, 1.0 - _EPS)
-    z = _SQRT2 * jax.lax.erf_inv(2.0 * centers - 1.0)
-    return (mu.astype(jnp.float32)[..., None]
-            + sigma.astype(jnp.float32)[..., None] * z)
+        planes = (c + 128 if k == 256 else c,)  # undo int8 storage offset
+    mu = mu.astype(jnp.float32)[..., None]
+    sigma = sigma.astype(jnp.float32)[..., None]
+    out = []
+    for p in planes:
+        centers = jnp.clip((p.astype(jnp.float32) + 0.5) / k, _EPS,
+                           1.0 - _EPS)
+        out.append(mu + sigma * (_SQRT2 * jax.lax.erf_inv(2.0 * centers
+                                                          - 1.0)))
+    return tuple(out)
 
 
 def _kernel(bt_ref, qpos_ref, win_ref, q_ref, kc_ref, km_ref, ks_ref,
             vc_ref, vm_ref, vs_ref, m_out, l_out, acc_out, m_scr, l_scr,
             acc_scr, *, bits: int, k: int, page: int, pages_per_split: int,
-            logit_cap):
+            logit_cap, scale: float):
     b = pl.program_id(0)
     s = pl.program_id(1)
     t = pl.program_id(2)
@@ -100,18 +108,20 @@ def _kernel(bt_ref, qpos_ref, win_ref, q_ref, kc_ref, km_ref, ks_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)                       # (KV, G, D)
-    D = q.shape[-1]
     # unpack+dequant BOTH pages up front: the VPU nibble unpack of V
     # overlaps the MXU's score dot instead of stalling behind it
-    kd = _dequant_page(kc_ref[0], km_ref[0], ks_ref[0], bits, k)
-    vd = _dequant_page(vc_ref[0], vm_ref[0], vs_ref[0], bits, k)
+    kd = _dequant_parts(kc_ref[0], km_ref[0], ks_ref[0], bits, k)
+    vd = _dequant_parts(vc_ref[0], vm_ref[0], vs_ref[0], bits, k)
 
-    # scores: (KV, G, D) x (KV, D, page) -> (KV, G, page)
-    sc = jax.lax.dot_general(
-        q * (D ** -0.5), jnp.transpose(kd, (1, 2, 0)),
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+    # scores: sum over column planes of (KV, G, Dc) x (KV, Dc, page)
+    sc = None
+    for h, kh in enumerate(kd):
+        qh = q_ref[0, h].astype(jnp.float32) * scale       # (KV, G, Dc)
+        part = jax.lax.dot_general(
+            qh, jnp.transpose(kh, (1, 2, 0)),
+            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        sc = part if sc is None else sc + part
     if logit_cap is not None:
         sc = logit_cap * jnp.tanh(sc / logit_cap)
     j = s * pages_per_split + t                            # logical page
@@ -126,11 +136,12 @@ def _kernel(bt_ref, qpos_ref, win_ref, q_ref, kc_ref, km_ref, ks_ref,
     alpha = jnp.exp(m_prev - m_new)                        # <= 1, finite
     pexp = jnp.where(valid, jnp.exp(sc - m_new[..., None]), 0.0)
     l_scr[...] = l_scr[...] * alpha + jnp.sum(pexp, axis=-1)
-    # (KV, G, page) x (KV, page, D) -> (KV, G, D)
-    acc_scr[...] = acc_scr[...] * alpha[..., None] + jax.lax.dot_general(
-        pexp, jnp.transpose(vd, (1, 0, 2)),
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+    # per column plane: (KV, G, page) x (KV, page, Dc) -> (KV, G, Dc)
+    for h, vh in enumerate(vd):
+        acc_scr[h] = acc_scr[h] * alpha[..., None] + jax.lax.dot_general(
+            pexp, jnp.transpose(vh, (1, 0, 2)),
+            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
     m_scr[...] = m_new
 
     @pl.when(t == pages_per_split - 1)
@@ -173,7 +184,11 @@ def paged_quant_attention(q: jax.Array, k_codes: jax.Array, k_mu: jax.Array,
     splits = max(1, min(splits, n_pages))
     pages_per_split = -(-n_pages // splits)
     k = 2 ** kv_bits
-    qg = q.reshape(B, KV, G, D)
+    Dc = k_codes.shape[-1]
+    n_planes = D // Dc               # 2 at kv4: even / odd column planes
+    # q in the kernel's planar column order: (B, plane, KV, G, Dc), plane
+    # h holding columns h, h + n_planes, ... (pack_int4's nibble order)
+    qg = jnp.moveaxis(q.reshape(B, KV, G, Dc, n_planes), -1, 1)
     block_tables = jnp.asarray(block_tables, jnp.int32)
     pad = splits * pages_per_split - n_pages
     if pad:
@@ -184,7 +199,6 @@ def paged_quant_attention(q: jax.Array, k_codes: jax.Array, k_mu: jax.Array,
     if window is None:
         window = BIG_WINDOW
     window = jnp.asarray(window, jnp.int32).reshape((1,))
-    Dc = k_codes.shape[-1]
 
     def page_map(b, s, t, bt, qp, win):
         return (bt[b, s * pages_per_split + t], 0, 0, 0)
@@ -193,19 +207,19 @@ def paged_quant_attention(q: jax.Array, k_codes: jax.Array, k_mu: jax.Array,
         return (bt[b, s * pages_per_split + t], 0, 0)
 
     def q_map(b, s, t, bt, qp, win):
-        return (b, 0, 0, 0)
+        return (b, 0, 0, 0, 0)
 
     def part_map(b, s, t, bt, qp, win):
         return (b, s, 0, 0)
 
     def acc_map(b, s, t, bt, qp, win):
-        return (b, s, 0, 0, 0)
+        return (b, s, 0, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, splits, pages_per_split),
         in_specs=[
-            pl.BlockSpec((1, KV, G, D), q_map),
+            pl.BlockSpec((1, n_planes, KV, G, Dc), q_map),
             pl.BlockSpec((1, page, KV, Dc), page_map),
             pl.BlockSpec((1, page, KV), stat_map),
             pl.BlockSpec((1, page, KV), stat_map),
@@ -216,28 +230,29 @@ def paged_quant_attention(q: jax.Array, k_codes: jax.Array, k_mu: jax.Array,
         out_specs=[
             pl.BlockSpec((1, 1, KV, G), part_map),
             pl.BlockSpec((1, 1, KV, G), part_map),
-            pl.BlockSpec((1, 1, KV, G, D), acc_map),
+            pl.BlockSpec((1, 1, n_planes, KV, G, Dc), acc_map),
         ],
         scratch_shapes=[
             pltpu.VMEM((KV, G), jnp.float32),
             pltpu.VMEM((KV, G), jnp.float32),
-            pltpu.VMEM((KV, G, D), jnp.float32),
+            pltpu.VMEM((n_planes, KV, G, Dc), jnp.float32),
         ],
     )
     m_part, l_part, acc_part = pl.pallas_call(
         functools.partial(_kernel, bits=kv_bits, k=k, page=page,
                           pages_per_split=pages_per_split,
-                          logit_cap=logit_cap),
+                          logit_cap=logit_cap, scale=D ** -0.5),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, splits, KV, G), jnp.float32),
             jax.ShapeDtypeStruct((B, splits, KV, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, splits, KV, G, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, splits, n_planes, KV, G, Dc),
+                                 jnp.float32),
         ],
-        compiler_params=pc.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=pc.interpret_mode(interpret),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(block_tables, q_pos, window, qg, k_codes, k_mu, k_sigma, v_codes,
       v_mu, v_sigma)
 
@@ -245,6 +260,8 @@ def paged_quant_attention(q: jax.Array, k_codes: jax.Array, k_mu: jax.Array,
     m_max = jnp.max(m_part, axis=1, keepdims=True)
     alpha = jnp.exp(m_part - m_max)                        # 0 for dry splits
     l = jnp.sum(alpha * l_part, axis=1)
-    acc = jnp.sum(alpha[..., None] * acc_part, axis=1)
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    acc = jnp.sum(alpha[:, :, None, ..., None] * acc_part, axis=1)
+    out = acc / jnp.maximum(l, 1e-30)[:, None, ..., None]
+    # back from planar to natural column order (B, KV, G, D)
+    out = jnp.moveaxis(out, 1, -1).reshape(B, KV, G, D)
     return out.astype(q.dtype).reshape(B, 1, H, D)

@@ -54,7 +54,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat as pc
 
 _SQRT2 = 1.4142135623730951
 _EPS = 1e-6
@@ -191,10 +190,10 @@ def qmatmul(a: jax.Array, w_packed: jax.Array, mu: jax.Array,
         out_specs=pl.BlockSpec((1, bm, bn), lambda j, kk, i: (kk, i, j)),
         out_shape=jax.ShapeDtypeStruct((ksplit, Mp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bk, bn), a.dtype)],
-        compiler_params=pc.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
-        interpret=pc.interpret_mode(interpret),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(a, w_packed, mu, sigma)
     return out.sum(axis=0)[:M, :N].astype(out_dtype)
 
@@ -271,10 +270,10 @@ def qmatmul_lut(a: jax.Array, w_packed: jax.Array, lut: jax.Array, *,
         out_specs=pl.BlockSpec((1, bm, bn), lambda j, kk, i: (kk, i, j)),
         out_shape=jax.ShapeDtypeStruct((ksplit, Mp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
-        compiler_params=pc.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
-        interpret=pc.interpret_mode(interpret),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(a, w_packed, lut)
     return out.sum(axis=0)[:M, :N].astype(out_dtype)
 
@@ -326,9 +325,9 @@ def qmatmul_a8(a_codes: jax.Array, a_scale: jax.Array, w_packed: jax.Array,
         out_specs=pl.BlockSpec((1, bm, bn), lambda j, kk, i: (kk, i, j)),
         out_shape=jax.ShapeDtypeStruct((ksplit, Mp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bk, bn), jnp.bfloat16)],
-        compiler_params=pc.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
-        interpret=pc.interpret_mode(interpret),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(a_scale, a_codes, w_packed, mu, sigma)
     return out.sum(axis=0)[:M, :N].astype(out_dtype)

@@ -40,7 +40,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat as pc
 
 _SQRT2 = 1.4142135623730951
 _EPS = 1e-6
@@ -127,10 +126,10 @@ def uniq_noise_fwd(w: jax.Array, mu: jax.Array, sigma: jax.Array,
                   data],
         out_specs=data,
         out_shape=jax.ShapeDtypeStruct((G, R, C), w.dtype),
-        compiler_params=pc.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
-        interpret=pc.interpret_mode(interpret),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(mode, w, mu, sigma, e01)
 
 
@@ -157,8 +156,8 @@ def uniq_noise_fwd_onchip(w: jax.Array, mu: jax.Array, sigma: jax.Array,
                   pl.BlockSpec(memory_space=pltpu.SMEM), data, stat, stat],
         out_specs=data,
         out_shape=jax.ShapeDtypeStruct((G, R, C), w.dtype),
-        compiler_params=pc.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
-        interpret=pc.interpret_mode(interpret),
+        interpret=pltpu.InterpretParams() if interpret else False,
     )(seed, mode, w, mu, sigma)

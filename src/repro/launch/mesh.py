@@ -10,20 +10,12 @@ from __future__ import annotations
 import jax
 
 
-def _make_mesh(shape, axes):
-    """jax<0.6 has no jax.sharding.AxisType; Auto is the default there."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """v5e-256 pod mesh: (data=16, model=16); multi-pod adds pod=2 (DCN)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -31,4 +23,5 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, max(n // data, 1))
-    return _make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

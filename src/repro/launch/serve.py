@@ -35,9 +35,14 @@ ran (CI smoke).  Every stream quantity — prompt tokens, lengths,
 arrivals AND per-request sampling seeds — derives from the single
 --seed, so a run is replayable end to end.
 
-Loads (or random-inits) weights, k-quantile-quantizes them to --w-bits,
-and serves synthetic prompts; the closed-batch path also reports greedy
-agreement with the bf16 model.
+Engine mode builds random weights from --seed directly in serving form on
+the device, one layer at a time (k-quantile codes at --w-bits 4/8, bf16
+matmul weights at 16; models/lm.py::build_serving_params), so a published-
+width model never exists as a dense f32 tree.  The closed-batch path
+random-inits the float model, quantizes it, and reports greedy agreement
+between the two.  Compute is bf16 (ModelOpts' default).  JAX's persistent
+compile cache lives in $JAX_COMPILATION_CACHE_DIR, or else in the
+checkout's .jax_cache (launch/compile_cache.py).
 """
 
 from __future__ import annotations
@@ -51,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import base as cb
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model
 from repro.models.lm import ModelOpts
 from repro.serve import serve as serve_lib
@@ -58,16 +64,38 @@ from repro.serve import telemetry as tele_lib
 from repro.serve.engine import Engine, EngineConfig, Request, SamplingParams
 
 
+# bf16 compute (ModelOpts' default).  Engine mode routes --a-bits through
+# EngineConfig to the real per-token int8 codec (lm.mm_a), so the model's
+# own layer-output fake-quant stays off (a_bits=32).
+SERVE_OPTS = ModelOpts(remat=False, attn_chunked_min_len=1 << 30,
+                       ssd_chunk=16)
+
+
 def _percentile(xs, q):
     return float(np.percentile(np.asarray(xs), q)) if len(xs) else 0.0
 
 
-def run_engine_stream(params, cfg, opts, args) -> dict:
+def engine_config(args) -> EngineConfig:
+    return EngineConfig(max_slots=args.max_slots, max_len=args.max_len,
+                        prefill_batch=args.prefill_batch,
+                        cache_mode=args.cache_mode, page_size=args.page_size,
+                        total_pages=args.total_pages, kv_bits=args.kv_bits,
+                        a_bits=args.a_bits,
+                        pool_bytes=args.pool_bytes,
+                        prefix_cache=args.prefix_cache,
+                        prefill_chunk=args.prefill_chunk,
+                        checkify=args.checkify,
+                        telemetry=not args.no_telemetry,
+                        profile_annotations=args.profile_annotations)
+
+
+def run_engine_stream(eng: Engine, args) -> dict:
     """Drive the engine with a Poisson arrival stream (rate req/s).
 
     Requests are submitted when their arrival time passes on the wall
     clock, so TTFT includes genuine queueing delay under load.
     """
+    cfg, ec = eng.cfg, eng.ec
     rng = np.random.default_rng(args.seed)
     n = args.requests
     sys_prompt = rng.integers(0, cfg.vocab, size=args.shared_prefix,
@@ -89,21 +117,9 @@ def run_engine_stream(params, cfg, opts, args) -> dict:
                         seed=int(seeds[i])))
             for i in range(n)]
 
-    ec = EngineConfig(max_slots=args.max_slots, max_len=args.max_len,
-                      prefill_batch=args.prefill_batch,
-                      cache_mode=args.cache_mode, page_size=args.page_size,
-                      total_pages=args.total_pages, kv_bits=args.kv_bits,
-                      a_bits=args.a_bits,
-                      pool_bytes=args.pool_bytes,
-                      prefix_cache=args.prefix_cache,
-                      prefill_chunk=args.prefill_chunk,
-                      checkify=args.checkify,
-                      telemetry=not args.no_telemetry,
-                      profile_annotations=args.profile_annotations)
     if args.checkify:
         print("[engine] checkify sanitizer ON (index OOB + NaN checks per "
               "jitted step; debug mode — expect a host sync per step)")
-    eng = Engine(params, cfg, opts, ec)
     if args.cache_mode == "paged":
         sch = eng.scheduler
         print(f"[engine] paged KV pool: {sch.total_pages} pages x "
@@ -115,6 +131,7 @@ def run_engine_stream(params, cfg, opts, args) -> dict:
     # compile the decode shape and EVERY prefill bucket this request set
     # will hit, outside the timed region
     from repro.serve.scheduler import bucket_len
+    t_warm = time.perf_counter()
     seen = set()
     for r in reqs:
         b = min(bucket_len(r.prompt.size, ec.min_bucket), ec.max_len)
@@ -126,6 +143,9 @@ def run_engine_stream(params, cfg, opts, args) -> dict:
     # earned by the stream itself, not inherited from compile warming
     eng.flush_prefix_cache()
     eng.reset_stats()
+    warmup_s = time.perf_counter() - t_warm
+    print(f"[engine] warmup (compile + {len(seen)} warm requests) "
+          f"{warmup_s:.2f}s")
 
     outs = []
     occupancy = []
@@ -165,6 +185,7 @@ def run_engine_stream(params, cfg, opts, args) -> dict:
         "evicted": eng.scheduler.n_evicted,
         "preemptions": eng.n_preemptions,
         "kv_utilization": eng.kv_utilization,
+        "warmup_s": warmup_s,
         **eng.stats(),
     }
     print(f"[engine] {stats['requests']} requests "
@@ -274,7 +295,7 @@ def run_closed_batch(params, cfg, opts, args) -> None:
     print("sample (quantized):", out_q[0][:16].tolist())
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)
     p.add_argument("--smoke", action="store_true")
@@ -360,6 +381,17 @@ def main(argv=None):
     p.add_argument("--debug-nans", action="store_true",
                    help="enable jax_debug_nans globally (first NaN "
                         "raises with a traceback; debug runs only)")
+    return p
+
+
+def build_engine_params(cfg, args):
+    """Random weights from --seed, built in serving form on the device."""
+    return model.build_for_serving(jax.random.PRNGKey(args.seed), cfg,
+                                   args.w_bits, dist=args.w_dist)
+
+
+def main(argv=None):
+    p = build_parser()
     args = p.parse_args(argv)
 
     if (args.metrics_out or args.trace_out) and not args.engine:
@@ -371,23 +403,15 @@ def main(argv=None):
     if args.debug_nans:
         jax.config.update("jax_debug_nans", True)
 
+    enable_compile_cache()
     cfg = cb.get_smoke(args.arch) if args.smoke else cb.get(args.arch)
-    opts = ModelOpts(compute_dtype=jnp.float32, remat=False,
-                     attn_chunked_min_len=1 << 30, ssd_chunk=16)
-    params = model.init(jax.random.PRNGKey(args.seed), cfg)
-
     if args.engine:
-        # engine mode routes --a-bits through EngineConfig to the real
-        # per-token int8 codec (lm.mm_a), not the closed-batch
-        # layer-output fake-quant — keep ServeConfig at a_bits=32 so
-        # make_serve_opts doesn't double-apply activation quantization
-        sc = serve_lib.ServeConfig(w_bits=args.w_bits, a_bits=32,
-                                   w_dist=args.w_dist)
-        params = serve_lib.prepare_params(params, sc)
-        opts = serve_lib.make_serve_opts(opts, sc)
-        run_engine_stream(params, cfg, opts, args)
+        params = build_engine_params(cfg, args)
+        run_engine_stream(Engine(params, cfg, SERVE_OPTS,
+                                 engine_config(args)), args)
     else:
-        run_closed_batch(params, cfg, opts, args)
+        params = model.init(jax.random.PRNGKey(args.seed), cfg)
+        run_closed_batch(params, cfg, SERVE_OPTS, args)
 
 
 if __name__ == "__main__":

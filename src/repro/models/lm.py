@@ -7,15 +7,18 @@ minutes on one CPU core) and gives the UNIQ gradual schedule a natural
 per-layer mode vector.
 
 Serving-time weights may be k-quantile-coded: any weight leaf replaced by a
-``{"q_codes", "q_mu", "q_sigma"}`` dict (see ``quantize_params_for_serving``)
-is dequantized on the fly inside the layer body — on TPU through the fused
-qmatmul Pallas kernel, elsewhere through the jnp reference (XLA fuses the
-dequant into the matmul operand).  HBM weight traffic drops 4x for W4.
+``{"q_codes", "q_mu", "q_sigma"}`` dict (see ``code_weight``) is
+dequantized inside the layer body by ``materialize`` (the jnp reference
+dequant) and multiplied with ``jnp.dot`` — on every backend, TPU included;
+the qmatmul Pallas kernels are not on the served path.  Whether XLA fuses
+the dequant into the dot operand, and so keeps W4's 4x cut in HBM weight
+traffic, is for a chip profile to show.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -171,69 +174,159 @@ def mm_a(x: Array, w, opts: "ModelOpts") -> Array:
                    preferred_element_type=jnp.float32).astype(x.dtype)
 
 
-def _quantize_leaf_empirical(leaf, bits: int, stacked: bool):
-    """Code one leaf against per-tensor empirical quantiles + codebook.
-
-    Stacked leaves (leading layer axis) get one codebook per layer — the
-    layer scan slices ``q_lut`` to ``(k,)`` alongside the codes.  Codes
-    reuse the weight-path storage conventions (int4 packing, int8 k=256
-    offset) so ``kernels.qmatmul_lut`` consumes them unchanged.
-    """
-    from repro.core import quantizers as Q
-    from repro.core.distributions import EmpiricalModel
-    k = 2 ** bits
-
-    def one(w):
+def _code_tensor(w, bits: int, dist: str):
+    """Serving dict of one weight tensor (no layer axis); Gaussian
+    statistics are per output channel."""
+    if dist == "empirical":
+        from repro.core import quantizers as Q
+        from repro.core.distributions import EmpiricalModel
         m = EmpiricalModel.fit(w)
-        return Q.kquantile_quantize(w, m, k), m.level_values(k)
+        codes = Q.kquantile_quantize(w, m, 2 ** bits, code_dtype=jnp.uint8)
+        return {"q_codes": packing.store_codes(codes, bits),
+                "q_lut": m.level_values(2 ** bits).astype(jnp.float32)}
+    if dist != "gaussian":
+        raise ValueError(f"dist must be gaussian|empirical, got {dist!r}")
+    from repro.core.uniq import quantize_tensor
+    qt = quantize_tensor(w, bits, per_channel=True)
+    return {"q_codes": qt.codes, "q_mu": qt.mu, "q_sigma": qt.sigma}
 
-    codes, lut = (jax.vmap(one) if stacked else lambda w: one(w))(leaf)
-    if bits == 4:
-        stored = packing.pack_int4(codes)
-    else:
-        stored = (codes - 128).astype(jnp.int8)
-    return {"q_codes": stored, "q_lut": lut.astype(jnp.float32)}
+
+_code_tensor_jit = jax.jit(_code_tensor, static_argnums=(1, 2))
 
 
-def quantize_params_for_serving(params, bits: int, quant_filter=None,
-                                per_channel: bool = True,
-                                dist: str = "gaussian",
+def code_weight(w, bits: int, *, dist: str = "gaussian",
+                stacked: bool = False):
+    """k-quantile code one weight leaf into its serving dict.
+
+    The one coder of the repo: ``quantize_params_for_serving`` calls it,
+    for trained trees and for ``build_serving_params``'s random weights
+    built on the device.  A ``stacked`` leaf (leading layer axis) is coded
+    one layer at a time, so only one layer's float temporaries are live;
+    its statistics are per (layer, channel) either way.  Every layer goes
+    through the same compiled program from a materialized input, so the
+    codes do not depend on where the float weights came from.
+
+    dist="gaussian" (paper-faithful): a view of ``core.uniq.
+    QuantizedTensor``, ``{"q_codes", "q_mu", "q_sigma"}``; dequant is
+    analytic.  dist="empirical": codes are taken against the per-tensor
+    (per-layer when stacked) empirical CDF and the dict carries the
+    k-level codebook instead (``{"q_codes", "q_lut"}`` — the paper's
+    "look-up table availability" assumption), matching how
+    ``cfg.dist="empirical"`` trains (core.uniq.transform_param).  Codes
+    use the weight-path storage conventions (int4 packing, int8 k=256
+    offset), so ``kernels.qmatmul_lut`` consumes them unchanged.
+    """
+    if not stacked:
+        return _code_tensor_jit(w, bits, dist)
+    layers = [_code_tensor_jit(w[i], bits, dist) for i in range(w.shape[0])]
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
+
+
+def _leaf_role(path: str, w, bits: int) -> str:
+    """How a leaf is served at ``bits``: "code" (k-quantile dict),
+    "dense" (a matmul weight left uncoded) or "keep" (norm scales,
+    routers)."""
+    from repro.core.uniq import default_quant_filter
+    if not default_quant_filter(path, w):
+        return "keep"
+    # only int4 packing needs an even trailing dim
+    if bits >= 16 or (bits == 4 and w.shape[-1] % 2):
+        return "dense"
+    return "code"
+
+
+def quantize_params_for_serving(params, bits: int, dist: str = "gaussian",
                                 stacked_prefixes=("layers", "enc_layers",
                                                   "dec_layers")):
-    """Replace eligible weight leaves by k-quantile code dicts.
-
-    dist="gaussian" (paper-faithful): each dict is a view of a
-    ``core.uniq.QuantizedTensor`` — the single source of truth for
-    code/statistic computation — flattened to the ``{"q_codes", "q_mu",
-    "q_sigma"}`` layout the layer bodies (and the MoE shard_map wspecs)
-    dispatch on; dequant is analytic.  dist="empirical": codes are taken
-    against the per-tensor empirical CDF and the dict carries the k-level
-    codebook instead (``{"q_codes", "q_lut"}`` — the paper's "look-up
-    table availability" assumption), matching how ``cfg.dist="empirical"``
-    trains (core.uniq.transform_param).  Only int4 packing needs an even
-    trailing dim, so the skip applies at bits == 4 alone; 8-bit leaves
-    with odd last dims are quantized like any other.
-    """
-    from repro.core.uniq import (default_quant_filter, path_str,
-                                 quantize_tensor)
+    """Replace the eligible weight leaves of a trained tree by k-quantile
+    code dicts (``code_weight``); other leaves are kept as they are."""
+    from repro.core.uniq import path_str
     if dist not in ("gaussian", "empirical"):
         raise ValueError(f"dist must be gaussian|empirical, got {dist!r}")
-    quant_filter = quant_filter or default_quant_filter
-    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
-    out = []
-    for kp, leaf in flat:
+
+    def one(kp, leaf):
         p = path_str(kp)
-        if not quant_filter(p, leaf) or (bits == 4 and leaf.shape[-1] % 2):
-            out.append(leaf)
-            continue
+        if _leaf_role(p, leaf, bits) != "code":
+            return leaf
         stacked = any(p.startswith(pre) for pre in stacked_prefixes)
-        if dist == "empirical":
-            out.append(_quantize_leaf_empirical(leaf, bits, stacked))
-            continue
-        qt = quantize_tensor(leaf, bits, per_channel=per_channel,
-                             stacked=stacked)
-        out.append({"q_codes": qt.codes, "q_mu": qt.mu, "q_sigma": qt.sigma})
-    return jax.tree_util.tree_unflatten(treedef, out)
+        return code_weight(leaf, bits, dist=dist, stacked=stacked)
+    return jax.tree_util.tree_map_with_path(one, params)
+
+
+# dense matmul weights of a built serving tree are served in the default
+# compute dtype (bf16)
+SERVE_DENSE_DTYPE = ModelOpts().compute_dtype
+
+
+def build_serving_params(rng: Array, cfg: ArchConfig, bits: int,
+                         dist: str = "gaussian"):
+    """Random-init the decoder-only tree and put it in serving form on the
+    device, one layer at a time.
+
+    The weights are ``init_params``'s, drawn from the same keys, and they
+    are coded by ``quantize_params_for_serving``, so the codes and
+    statistics are bit-identical to coding ``init_params(rng, cfg)``.  No
+    f32 layer stack ever exists: each layer, and then each leaf outside
+    the stack (embedding, final norm, LM head), is drawn alone by one
+    program, coded (bits 4/8), and written into the stacked served tree,
+    whose buffers are donated from layer to layer.  Matmul weights left
+    dense (bits 16, or an odd trailing dim at bits 4) are cast to
+    ``SERVE_DENSE_DTYPE`` inside the drawing program, so they never leave
+    it in f32; norm scales stay f32.  Only the weights to be coded leave
+    it in f32, as the coder's materialized input: at most one layer
+    (0.79 GB at granite_3_8b width) or one top-level leaf (the 0.81 GB
+    embedding) at a time.  At that width the W4 tree is 4.5 GB, where the
+    f32 init alone would need 33.5 GB.
+
+    The coder is not fused into the drawing program: there XLA splits the
+    statistics' reductions differently (a reduce-window tree on the CPU),
+    and the embedding's ``q_sigma`` then differs from a trained tree's
+    coding in its last bit.  Coding a materialized input is what keeps
+    the two paths one program.
+    """
+    keys = jax.random.split(rng, 16)
+    layers = None
+    for i, key in enumerate(_layer_keys(keys, cfg)):
+        layer = quantize_params_for_serving(
+            _draw_layer(key, cfg, bits), bits, dist,
+            stacked_prefixes=())["layers"]
+        if layers is None:
+            layers = jax.tree.map(
+                lambda x: jnp.zeros((cfg.n_layers,) + x.shape, x.dtype),
+                layer)
+        # the host would otherwise enqueue later layers' draws while the
+        # device is still coding this one, and each draw's output is
+        # allocated when it is enqueued
+        layers = jax.block_until_ready(_set_layer(layers, layer, i))
+    params = {"layers": layers}
+    for name in jax.eval_shape(lambda k: _init_top(k, cfg), keys):
+        params.update(jax.block_until_ready(quantize_params_for_serving(
+            _draw_top_leaf(keys, cfg, bits, name), bits, dist)))
+    return params
+
+
+def _cast_dense(tree, bits: int):
+    """``tree`` with its leaves that stay dense at ``bits`` in
+    ``SERVE_DENSE_DTYPE``."""
+    from repro.core.uniq import path_str
+    return jax.tree_util.tree_map_with_path(
+        lambda kp, w: w.astype(SERVE_DENSE_DTYPE)
+        if _leaf_role(path_str(kp), w, bits) == "dense" else w, tree)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _draw_layer(key, cfg: ArchConfig, bits: int):
+    return _cast_dense({"layers": init_layer(key, cfg)}, bits)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _draw_top_leaf(keys, cfg: ArchConfig, bits: int, name: str):
+    return _cast_dense({name: _init_top(keys, cfg)[name]}, bits)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _set_layer(stack, layer, i):
+    return jax.tree.map(lambda s, x: s.at[i].set(x), stack, layer)
 
 
 # --------------------------------------------------------------------------
@@ -248,42 +341,59 @@ def norm_param(cfg: ArchConfig, *shape):
     return jnp.ones(shape, jnp.float32)
 
 
-def init_params(rng: Array, cfg: ArchConfig) -> Dict[str, Any]:
-    """Decoder-only parameter tree (dense / moe / vlm families)."""
-    L, d, f, V = cfg.n_layers, cfg.d_model, cfg.d_ff, cfg.vocab
+def _layer_matmuls(cfg: ArchConfig) -> Dict[str, Any]:
+    """name -> (init key index, per-layer shape) of a layer's matmul
+    weights (and router); every fan-in is the second-to-last axis."""
+    d, f = cfg.d_model, cfg.d_ff
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    keys = jax.random.split(rng, 16)
-
-    layers: Dict[str, Any] = {
-        "attn_norm": norm_param(cfg, L, d),
-        "wq": dense_init(keys[0], (L, d, H * hd)),
-        "wk": dense_init(keys[1], (L, d, KV * hd)),
-        "wv": dense_init(keys[2], (L, d, KV * hd)),
-        "wo": dense_init(keys[3], (L, H * hd, d)),
-        "mlp_norm": norm_param(cfg, L, d),
-    }
-    if cfg.post_norms:
-        layers["post_attn_norm"] = jnp.ones((L, d), jnp.float32)
-        layers["post_mlp_norm"] = jnp.ones((L, d), jnp.float32)
+    shapes = {"wq": (0, (d, H * hd)), "wk": (1, (d, KV * hd)),
+              "wv": (2, (d, KV * hd)), "wo": (3, (H * hd, d))}
     if cfg.is_moe:
         E = cfg.n_experts
-        layers["router"] = dense_init(keys[4], (L, d, E))
-        layers["eg"] = dense_init(keys[5], (L, E, d, f))
-        layers["eu"] = dense_init(keys[6], (L, E, d, f))
-        layers["ed"] = dense_init(keys[7], (L, E, f, d), in_axis=-2)
+        shapes.update(router=(4, (d, E)), eg=(5, (E, d, f)),
+                      eu=(6, (E, d, f)), ed=(7, (E, f, d)))
     else:
-        layers["w_gate"] = dense_init(keys[5], (L, d, f))
-        layers["w_up"] = dense_init(keys[6], (L, d, f))
-        layers["w_down"] = dense_init(keys[7], (L, f, d))
+        shapes.update(w_gate=(5, (d, f)), w_up=(6, (d, f)),
+                      w_down=(7, (f, d)))
+    return shapes
 
+
+def init_layer(rng: Array, cfg: ArchConfig) -> Dict[str, Any]:
+    """One decoder layer's parameters (no leading layer axis), drawn from
+    the layer's own key."""
+    d = cfg.d_model
+    keys = jax.random.split(rng, 8)
+    params = {"attn_norm": norm_param(cfg, d), "mlp_norm": norm_param(cfg, d)}
+    if cfg.post_norms:
+        params["post_attn_norm"] = jnp.ones((d,), jnp.float32)
+        params["post_mlp_norm"] = jnp.ones((d,), jnp.float32)
+    params.update({name: dense_init(keys[i], shape)
+                   for name, (i, shape) in _layer_matmuls(cfg).items()})
+    return params
+
+
+def _layer_keys(keys: Array, cfg: ArchConfig) -> Array:
+    return jax.random.split(keys[10], cfg.n_layers)
+
+
+def _init_top(keys: Array, cfg: ArchConfig) -> Dict[str, Any]:
+    """The parameters outside the layer stack."""
     params: Dict[str, Any] = {
-        "embed": embed_init(keys[8], (V, d)),
-        "layers": layers,
-        "final_norm": norm_param(cfg, d),
+        "embed": embed_init(keys[8], (cfg.vocab, cfg.d_model)),
+        "final_norm": norm_param(cfg, cfg.d_model),
     }
     if not cfg.tie_embeddings:
-        params["lm_head"] = dense_init(keys[9], (d, V))
+        params["lm_head"] = dense_init(keys[9], (cfg.d_model, cfg.vocab))
     return params
+
+
+def init_params(rng: Array, cfg: ArchConfig) -> Dict[str, Any]:
+    """Decoder-only parameter tree (dense / moe / vlm families): the
+    stack of ``init_layer`` over per-layer keys, plus the leaves outside
+    the stack."""
+    keys = jax.random.split(rng, 16)
+    layers = jax.vmap(lambda k: init_layer(k, cfg))(_layer_keys(keys, cfg))
+    return {**_init_top(keys, cfg), "layers": layers}
 
 
 # --------------------------------------------------------------------------
@@ -420,11 +530,11 @@ def _moe_ep_sharded(h, router_w, eg, eu, ed, mcfg, opts: ModelOpts):
                 mcfg, shard_idx=idx)
             return jax.lax.psum(y.reshape(B_, S_, d_), ("model",) + fa)
 
-        return _shard_map_compat(
-            region, mesh,
+        return jax.shard_map(
+            region, mesh=mesh,
             in_specs=(P(bspec, None, None), P(None, None),
                       wspec(eg, 2), wspec(eu, 2), wspec(ed, 1)),
-            out_specs=P(bspec, None, None),
+            out_specs=P(bspec, None, None), check_vma=False,
         )(h, router_w, eg, eu, ed)
 
     dpn = int(np.prod([mesh.shape[a] for a in dp])) if dp else 1
@@ -437,25 +547,13 @@ def _moe_ep_sharded(h, router_w, eg, eu, ed, mcfg, opts: ModelOpts):
             dn = jax.lax.all_gather(dn, fa, axis=2, tiled=True)
         return moe_lib.moe_ffn(hb, rw, g, u, dn, mcfg, axis_name="model")
 
-    return _shard_map_compat(
-        region, mesh,
+    return jax.shard_map(
+        region, mesh=mesh,
         in_specs=(P(bspec, None, None), P(None, None),
                   P("model", f_in, None), P("model", f_in, None),
                   P("model", None, f_in)),
-        out_specs=P(bspec, None, None),
+        out_specs=P(bspec, None, None), check_vma=False,
     )(h, router_w, eg, eu, ed)
-
-
-def _shard_map_compat(f, mesh, in_specs, out_specs):
-    """jax>=0.8 renamed check_rep -> check_vma, and jax<0.6 has no
-    top-level jax.shard_map at all; support all three vintages."""
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
 
 
 def _ffn_block(x, lp, cfg: ArchConfig, opts: ModelOpts):

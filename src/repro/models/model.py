@@ -21,6 +21,7 @@ from repro.models.lm import ModelOpts
 
 __all__ = ["ModelOpts", "init", "loss_fn", "prefill", "decode",
            "cache_specs", "init_cache", "quantize_for_serving",
+           "build_for_serving",
            "supports_slot_cache", "init_slot_cache", "cache_insert",
            "supports_paged_cache", "init_paged_cache",
            "cache_insert_paged", "prefill_chunk"]
@@ -189,9 +190,18 @@ def prefill_chunk(params, cfg: ArchConfig, opts: ModelOpts, cache, tokens,
                             write_pages, write_rows, block_tables, last_idx)
 
 
-def quantize_for_serving(params, bits: int, per_channel: bool = True,
-                         dist: str = "gaussian"):
-    """k-quantile-code all matmul weights for the serving path (UNIQ)."""
-    return lm.quantize_params_for_serving(params, bits,
-                                          per_channel=per_channel,
-                                          dist=dist)
+def quantize_for_serving(params, bits: int, dist: str = "gaussian"):
+    """k-quantile-code all matmul weights of a trained tree for the
+    serving path (UNIQ)."""
+    return lm.quantize_params_for_serving(params, bits, dist=dist)
+
+
+def build_for_serving(rng: jax.Array, cfg: ArchConfig, bits: int,
+                      dist: str = "gaussian"):
+    """``quantize_for_serving(init(rng, cfg), bits)``'s weights, built in
+    serving form on the device layer by layer, with dense matmul weights
+    in bf16 (decoder-only families; see lm.build_serving_params)."""
+    if cfg.family in ("audio", "ssm", "hybrid"):
+        raise ValueError(f"no streamed serving build for family "
+                         f"{cfg.family}; use init + quantize_for_serving")
+    return lm.build_serving_params(rng, cfg, bits, dist=dist)

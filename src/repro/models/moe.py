@@ -28,7 +28,6 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.collectives import axis_size
 
 Array = jax.Array
 
@@ -123,7 +122,7 @@ def moe_ffn(x: Array, router_w: Array, gate_w: Array, up_w: Array,
                           shard_idx=0, n_shards=1, act_fn=act_fn)
     else:
         idx = jax.lax.axis_index(axis_name)
-        n = axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
         y = moe_ffn_local(xt, router_w, gate_w, up_w, down_w, cfg,
                           shard_idx=idx, n_shards=n, act_fn=act_fn)
         y = jax.lax.psum(y, axis_name)

@@ -30,14 +30,6 @@ import jax.numpy as jnp
 Array = jax.Array
 
 
-def axis_size(axis_name):
-    """jax<0.6 has no jax.lax.axis_size; psum(1) is the portable spelling.
-    Public: models/moe.py uses it inside shard_map regions too."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def _absmax_quant(g: Array, bits: int = 8):
     qmax = 2.0 ** (bits - 1) - 1.0
     # keepdims always: scale must broadcast against codes after the
@@ -64,7 +56,7 @@ def compressed_pmean(tree: Any, axis_name: str, bits: int = 8) -> Any:
 
     Must run inside a shard_map region where ``axis_name`` is manual.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def one(g):
         if g.ndim <= 1 or g.size <= 128 or not jnp.issubdtype(
@@ -114,17 +106,7 @@ def make_pod_compressed_grads(loss_and_grads_fn, mesh, bits: int = 8):
 
 
 def pod_shard_map(f, mesh, in_specs, out_specs, manual=("pod",)):
-    """shard_map with only ``manual`` axes manual (partial-manual region).
-
-    jax>=0.6 spells this jax.shard_map(axis_names=...); older releases
-    spell it jax.experimental.shard_map(auto=<complement>).
-    """
-    try:
-        return jax.shard_map(f, mesh=mesh, axis_names=set(manual),
-                             in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-        auto = frozenset(mesh.axis_names) - set(manual)
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False, auto=auto)
+    """shard_map with only ``manual`` axes manual (partial-manual region)."""
+    return jax.shard_map(f, mesh=mesh, axis_names=set(manual),
+                         in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)

@@ -50,10 +50,10 @@ the block-table array in paged mode); the prefill sees (prefill_batch,
 bucket) token blocks, bucket a power of two — the compile count is
 bounded by the bucket count, not the traffic.
 
-The weights may be k-quantile coded (``model.quantize_for_serving``): both
-prefill and decode then dequantize on the fly through the qmatmul path,
-which is exactly the deployment regime the paper's BOPs argument targets
-(EXPERIMENTS.md Sec. Perf).
+The weights may be k-quantile coded (``model.build_for_serving`` or
+``model.quantize_for_serving``): both prefill and decode then dequantize
+on the fly (``lm.materialize`` + ``jnp.dot``), which is the deployment
+regime the paper's BOPs argument targets (EXPERIMENTS.md Sec. Perf).
 """
 
 from __future__ import annotations
@@ -368,6 +368,7 @@ class Engine:
                 err, out = checked(*args)
                 _ck.check_error(err)
                 return out
+            shim.lower = checked.lower
             return shim
 
         def _annot(fn, name):
@@ -382,9 +383,9 @@ class Engine:
                     return fn(*args)
             return wrapped
 
-        self._decode_step = _annot(_jit(
-            decode_paged if self.paged else decode_slot, donate=(1,)),
-            "engine.decode")
+        self._decode_jit = _jit(decode_paged if self.paged else decode_slot,
+                                donate=(1,))
+        self._decode_step = _annot(self._decode_jit, "engine.decode")
         self._prefill_step = _annot(_jit(prefill_fn), "engine.prefill")
         self._cache_insert = _annot(_jit(
             model.cache_insert_paged if self.paged else model.cache_insert,
@@ -513,6 +514,21 @@ class Engine:
     def chrome_trace(self) -> dict:
         """Chrome-trace/Perfetto JSON of the recorded spans."""
         return self.telemetry.tracer.to_chrome_trace()
+
+    @property
+    def kv_pool(self):
+        """The device KV pool pytree (read it; the engine owns it)."""
+        return self._cache
+
+    def lower_decode(self):
+        """The engine's own jitted decode step lowered at this engine's
+        shapes (a ``jax.stages.Lowered``): ``.compile().as_text()`` is the
+        program the device runs, e.g. to check which kernels it holds."""
+        args = [self.params, self._cache, self._cur_tok, self._positions]
+        if self.paged:
+            args.append(self.scheduler.block_tables)
+        args += [self._temps, self._topks, self._seeds]
+        return self._decode_jit.lower(*args)
 
     @property
     def has_work(self) -> bool:

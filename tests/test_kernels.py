@@ -227,12 +227,12 @@ def test_empirical_materialize_matches_lut_kernel(bits):
     "q_lut"} dict) and the qmatmul_lut kernel consume the same storage
     layout: x @ materialize(w) must equal the kernel's output, for both
     flat and stacked (per-layer codebook) leaves."""
-    from repro.models.lm import _quantize_leaf_empirical, materialize
+    from repro.models.lm import code_weight, materialize
     k = 2 ** bits
     K, N, L = 64, 32, 3
     key = jax.random.PRNGKey(3)
     w = jax.random.normal(key, (K, N)) ** 3 * 0.05
-    d = _quantize_leaf_empirical(w, bits, stacked=False)
+    d = code_weight(w, bits, dist="empirical")
     a = jax.random.normal(jax.random.PRNGKey(4), (16, K)) * 0.1
     lut2d = jnp.broadcast_to(d["q_lut"][:, None], (k, N))
     out_k = ops.qmatmul_lut(a, d["q_codes"], lut2d, bits=bits,
@@ -242,7 +242,7 @@ def test_empirical_materialize_matches_lut_kernel(bits):
                                atol=1e-5)
     # stacked: one codebook per layer, sliced or gathered whole
     ws = jax.random.normal(key, (L, K, N)) * 0.05
-    ds = _quantize_leaf_empirical(ws, bits, stacked=True)
+    ds = code_weight(ws, bits, dist="empirical", stacked=True)
     assert ds["q_lut"].shape == (L, k)
     whole = materialize(ds, jnp.float32)
     for l in range(L):
