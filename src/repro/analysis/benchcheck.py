@@ -1,7 +1,7 @@
 """Bench-artifact schema validation (the ``bench`` uniqcheck pass).
 
 BENCH_engine.json is a committed artifact other tooling consumes (the
-README serving table, traceview attribution, regression eyeballing).
+README serving table, regression eyeballing).
 A bench refresh that silently drops the latency distribution — the
 TTFT/ITL/queue-wait percentiles the serving story is argued from —
 must fail CI, not be discovered a PR later.  Purely structural: values

@@ -48,7 +48,7 @@ checkout's .jax_cache (launch/compile_cache.py).
 from __future__ import annotations
 
 import argparse
-import json
+import contextlib
 import time
 
 import jax
@@ -86,7 +86,8 @@ def engine_config(args) -> EngineConfig:
                         prefill_chunk=args.prefill_chunk,
                         checkify=args.checkify,
                         telemetry=not args.no_telemetry,
-                        profile_annotations=args.profile_annotations)
+                        profile_annotations=args.profile_annotations
+                        or bool(args.trace_out))
 
 
 def run_engine_stream(eng: Engine, args) -> dict:
@@ -149,20 +150,30 @@ def run_engine_stream(eng: Engine, args) -> dict:
 
     outs = []
     occupancy = []
-    t0 = time.perf_counter()
-    next_i = 0
-    while next_i < n or eng.has_work:
-        now = time.perf_counter() - t0
-        while next_i < n and arrivals[next_i] <= now:
-            reqs[next_i].arrival_time = t0 + arrivals[next_i]
-            eng.submit(reqs[next_i])
-            next_i += 1
-        if not eng.has_work:
-            time.sleep(min(1e-3, max(0.0, arrivals[next_i] - now)))
-            continue
-        outs.extend(eng.step())
-        occupancy.append(eng.scheduler.n_running)
-    wall = time.perf_counter() - t0
+    # --trace-out: the stream runs under the JAX profiler, the engine's
+    # spans annotated (engine_config), so one Perfetto timeline holds the
+    # engine's phases beside the device ops
+    profile = jax.profiler.trace(args.trace_out, create_perfetto_trace=True) \
+        if args.trace_out else contextlib.nullcontext()
+    with profile:
+        t0 = time.perf_counter()
+        next_i = 0
+        while next_i < n or eng.has_work:
+            now = time.perf_counter() - t0
+            while next_i < n and arrivals[next_i] <= now:
+                reqs[next_i].arrival_time = t0 + arrivals[next_i]
+                eng.submit(reqs[next_i])
+                next_i += 1
+            if not eng.has_work:
+                time.sleep(min(1e-3, max(0.0, arrivals[next_i] - now)))
+                continue
+            outs.extend(eng.step())
+            occupancy.append(eng.scheduler.n_running)
+        wall = time.perf_counter() - t0
+    if args.trace_out:
+        print(f"[engine] profiler trace -> {args.trace_out} (open "
+              f"plugins/profile/*/perfetto_trace.json.gz in "
+              f"ui.perfetto.dev)")
 
     new_tokens = sum(len(o.token_ids) for o in outs)
     ttfts = [o.ttft_s for o in outs]
@@ -237,7 +248,7 @@ def run_engine_stream(eng: Engine, args) -> dict:
             f"lost requests: {eng.scheduler.n_submitted} submitted, "
             f"{stats['requests']} completed")
 
-    # -- telemetry exports (the traceview/CI consumables) -------------------
+    # -- telemetry exports ----------------------------------------------------
     if eng.telemetry.enabled:
         reg = eng.telemetry.registry
         itl = tele_lib.percentile_summary(reg["itl_s"], scale=1e3)
@@ -247,27 +258,16 @@ def run_engine_stream(eng: Engine, args) -> dict:
               f"p95 {qw['p95']:.1f}ms")
         stats.update({f"itl_{k}_ms": v for k, v in itl.items()})
         stats.update({f"queue_wait_{k}_ms": v for k, v in qw.items()})
-    if args.metrics_out or args.trace_out:
+    if args.metrics_out:
         # the driver knows what the engine doesn't: quantizer + workload
         meta = {"w_bits": args.w_bits, "a_bits": args.a_bits,
                 "dist": args.w_dist, "smoke": args.smoke,
                 "rate": args.rate, "requests": args.requests,
                 "shared_prefix": args.shared_prefix}
-        if args.metrics_out:
-            snap = eng.metrics_snapshot(meta)
-            with open(args.metrics_out, "w") as fh:
-                json.dump(snap, fh, indent=2, sort_keys=True)
-            with open(args.metrics_out + ".prom", "w") as fh:
-                fh.write(eng.telemetry.registry.to_prometheus())
-            print(f"[engine] metrics snapshot -> {args.metrics_out} "
-                  f"(+ .prom exposition)")
-        if args.trace_out:
-            trace = eng.chrome_trace()
-            with open(args.trace_out, "w") as fh:
-                json.dump(trace, fh)
-            print(f"[engine] chrome trace -> {args.trace_out} "
-                  f"({len(trace['traceEvents'])} events; load in "
-                  f"chrome://tracing or ui.perfetto.dev)")
+        eng.telemetry.write_metrics(args.metrics_out,
+                                    eng.config_meta() | meta)
+        print(f"[engine] metrics snapshot -> {args.metrics_out} "
+              f"(+ .prom exposition)")
     return stats
 
 
@@ -363,16 +363,19 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--metrics-out", default=None, metavar="PATH",
                    help="write the metrics snapshot JSON here (plus the "
                         "Prometheus text exposition at PATH.prom)")
-    p.add_argument("--trace-out", default=None, metavar="PATH",
-                   help="write the Chrome-trace JSON of the run here "
-                        "(open in chrome://tracing / ui.perfetto.dev)")
+    p.add_argument("--trace-out", default=None, metavar="DIR",
+                   help="run the request stream under the JAX profiler "
+                        "and write its trace directory here, engine spans "
+                        "annotated (implies --profile-annotations; open "
+                        "the perfetto_trace.json.gz in ui.perfetto.dev)")
     p.add_argument("--no-telemetry", action="store_true",
                    help="disable metrics + tracing (A/B the overhead; "
                         "token streams are bit-identical either way)")
     p.add_argument("--profile-annotations", action="store_true",
-                   help="wrap the jitted engine steps in jax.profiler "
-                        "TraceAnnotations (names show up in device "
-                        "profiles captured by jax.profiler)")
+                   help="open a jax.profiler TraceAnnotation engine.<span> "
+                        "with every engine span, so the engine's phases "
+                        "show up in device profiles captured by "
+                        "jax.profiler")
     # opt-in debug sanitizers (both OFF by default; DESIGN.md Sec. 10)
     p.add_argument("--checkify", action="store_true",
                    help="wrap the engine's jitted steps with "
@@ -396,9 +399,9 @@ def main(argv=None):
 
     if (args.metrics_out or args.trace_out) and not args.engine:
         p.error("--metrics-out/--trace-out require --engine")
-    if (args.metrics_out or args.trace_out) and args.no_telemetry:
-        p.error("--metrics-out/--trace-out need telemetry enabled "
-                "(drop --no-telemetry)")
+    if args.metrics_out and args.no_telemetry:
+        p.error("--metrics-out needs telemetry enabled (drop "
+                "--no-telemetry)")
 
     if args.debug_nans:
         jax.config.update("jax_debug_nans", True)

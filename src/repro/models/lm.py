@@ -123,9 +123,15 @@ def is_qweight(w) -> bool:
 
 
 def materialize(w, dtype):
-    """Return a dense (possibly dequantized) weight in compute dtype."""
+    """Return a dense (possibly dequantized) weight in compute dtype; a
+    coded leaf is dequantized under the ``dequant`` scope."""
     if not is_qweight(w):
         return w.astype(dtype)
+    with jax.named_scope("dequant"):
+        return _dequantize(w, dtype)
+
+
+def _dequantize(w, dtype):
     codes = w["q_codes"]
     bits = 4 if codes.dtype == jnp.uint8 else 8
     if bits == 4:
@@ -150,7 +156,8 @@ def materialize(w, dtype):
 
 def mm(x: Array, w) -> Array:
     """x @ w where w is a dense array or a quantized-weight dict."""
-    return jnp.dot(x, materialize(w, x.dtype))
+    with jax.named_scope("matmul"):
+        return jnp.dot(x, materialize(w, x.dtype))
 
 
 def mm_a(x: Array, w, opts: "ModelOpts") -> Array:
@@ -168,10 +175,12 @@ def mm_a(x: Array, w, opts: "ModelOpts") -> Array:
     if bits >= 32 or not is_qweight(w):
         return mm(x, w)
     from repro.core import activations as act
-    codes, scale = act.quant_act(x, bits, act.act_scale(x, bits, axis=-1))
-    a = codes.astype(jnp.float32) * scale
-    return jnp.dot(a, materialize(w, jnp.float32),
-                   preferred_element_type=jnp.float32).astype(x.dtype)
+    with jax.named_scope("matmul"):
+        codes, scale = act.quant_act(x, bits,
+                                     act.act_scale(x, bits, axis=-1))
+        a = codes.astype(jnp.float32) * scale
+        return jnp.dot(a, materialize(w, jnp.float32),
+                       preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 def _code_tensor(w, bits: int, dist: str):
@@ -454,11 +463,12 @@ def _attn_block(x, lp, cfg: ArchConfig, opts: ModelOpts, positions, window,
     p = attn.AttnParams(window=window, logit_cap=cfg.attn_logit_cap,
                         causal=True)
     pos1d = positions[0]
-    if S >= opts.attn_chunked_min_len:
-        o = attn.chunked_attention(q, k, v, pos1d, pos1d, p,
-                                   kv_chunk=opts.kv_chunk)
-    else:
-        o = attn.full_attention(q, k, v, pos1d, pos1d, p)
+    with jax.named_scope("attention"):
+        if S >= opts.attn_chunked_min_len:
+            o = attn.chunked_attention(q, k, v, pos1d, pos1d, p,
+                                       kv_chunk=opts.kv_chunk)
+        else:
+            o = attn.full_attention(q, k, v, pos1d, pos1d, p)
     o = shard_act(o.reshape(B, S, H * hd), opts, "dp", None, "tp")
     o = shard_act(mm_a(o, lp["wo"], opts), opts, "dp", None, None)
     if cfg.post_norms:
@@ -610,11 +620,12 @@ def decoder_layer(x, lp, cfg: ArchConfig, opts: ModelOpts, positions,
 # --------------------------------------------------------------------------
 
 def _embed_tokens(params, cfg: ArchConfig, opts: ModelOpts, tokens):
-    emb = materialize(params["embed"], opts.compute_dtype)
-    x = jnp.take(emb, tokens, axis=0)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
-    return shard_act(x, opts, "dp", None, None)
+    with jax.named_scope("embed"):
+        emb = materialize(params["embed"], opts.compute_dtype)
+        x = jnp.take(emb, tokens, axis=0)
+        if cfg.embed_scale:
+            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        return shard_act(x, opts, "dp", None, None)
 
 
 def _head_weight(params, cfg: ArchConfig):
@@ -698,6 +709,24 @@ def _uniq_layer(lp, uniq_scan, layer_idx):
     return out
 
 
+def _scoped(fn, name: str):
+    """``fn`` with its whole body under the named scope ``name`` (the
+    step programs' scope vocabulary: ``serve.engine.OP_SCOPES``)."""
+    @functools.wraps(fn)
+    def scoped(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+    return scoped
+
+
+def _logits(params, cfg: ArchConfig, x):
+    """f32 logits (B, V) of the final-normed rows ``x`` (B, d)."""
+    with jax.named_scope("matmul"):
+        logits = jnp.dot(x, materialize(_head_weight(params, cfg), x.dtype),
+                         preferred_element_type=jnp.float32)
+    return softcap(logits, cfg.final_logit_cap)
+
+
 def _scan_layers(params, cfg: ArchConfig, opts: ModelOpts, x, positions,
                  collect_kv: bool = False, uniq_scan=None):
     windows = _window_schedule(cfg)
@@ -714,7 +743,7 @@ def _scan_layers(params, cfg: ArchConfig, opts: ModelOpts, x, positions,
             return _maybe_quant_act(h, opts), kv
         return decoder_layer(h, lp, cfg, opts, positions, window), None
 
-    f = body
+    f = _scoped(body, "layer")
     if opts.remat:
         f = jax.checkpoint(body, prevent_cse=False)
     return jax.lax.scan(f, x, (params["layers"], windows, layer_ids))
@@ -771,12 +800,11 @@ def forward_prefill(params, cfg: ArchConfig, opts: ModelOpts, batch,
     B, S, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     x, kvs = _scan_layers(params, cfg, opts, x, positions, collect_kv=True)
-    x = _norm_final(x, params, cfg)
-    last = x[:, -1] if last_idx is None \
-        else x[jnp.arange(B), jnp.clip(last_idx, 0, S - 1)]
-    logits = jnp.dot(last, materialize(_head_weight(params, cfg), last.dtype),
-                     preferred_element_type=jnp.float32)
-    logits = softcap(logits, cfg.final_logit_cap)
+    with jax.named_scope("head"):
+        x = _norm_final(x, params, cfg)
+        last = x[:, -1] if last_idx is None \
+            else x[jnp.arange(B), jnp.clip(last_idx, 0, S - 1)]
+        logits = _logits(params, cfg, last)
     cache = kvs if isinstance(kvs, dict) else {"k": kvs[0], "v": kvs[1]}
     if pad_to and pad_to > S:
         # every cache leaf is (L, B, S, ...): pad the position axis
@@ -863,8 +891,9 @@ def cache_insert_paged(cache, prefill_cache, page_tables):
         kv = kv.reshape(L, G, n_pages, page, *kv.shape[3:])
         return pool.at[:, page_tables].set(kv.astype(pool.dtype))
 
-    return {name: scatter(cache[name], prefill_cache[name])
-            for name in cache}
+    with jax.named_scope("kv_write"):
+        return {name: scatter(cache[name], prefill_cache[name])
+                for name in cache}
 
 
 def prefill_chunk(params, cfg: ArchConfig, opts: ModelOpts, cache, tokens,
@@ -938,23 +967,26 @@ def prefill_chunk(params, cfg: ArchConfig, opts: ModelOpts, cache, tokens,
                             causal=True)
         kc = dict(kc)
         if quant:
-            k_st, k_mu, k_sig = kvq.quantize_kv(k, opts.kv_bits)
-            v_st, v_mu, v_sig = kvq.quantize_kv(v, opts.kv_bits)
-            for name, val in (("k_codes", k_st), ("k_mu", k_mu),
-                              ("k_sigma", k_sig), ("v_codes", v_st),
-                              ("v_mu", v_mu), ("v_sigma", v_sig)):
-                kc[name] = kc[name].at[write_pages, write_rows].set(
-                    val.astype(kc[name].dtype))
-            o = attn.paged_prefill_attention_quant(q, kc, block_tables,
-                                                   pos2d, p,
-                                                   kv_bits=opts.kv_bits)
+            with jax.named_scope("kv_write"):
+                k_st, k_mu, k_sig = kvq.quantize_kv(k, opts.kv_bits)
+                v_st, v_mu, v_sig = kvq.quantize_kv(v, opts.kv_bits)
+                for name, val in (("k_codes", k_st), ("k_mu", k_mu),
+                                  ("k_sigma", k_sig), ("v_codes", v_st),
+                                  ("v_mu", v_mu), ("v_sigma", v_sig)):
+                    kc[name] = kc[name].at[write_pages, write_rows].set(
+                        val.astype(kc[name].dtype))
+            with jax.named_scope("attention"):
+                o = attn.paged_prefill_attention_quant(
+                    q, kc, block_tables, pos2d, p, kv_bits=opts.kv_bits)
         else:
-            kc["k"] = kc["k"].at[write_pages, write_rows].set(
-                k.astype(kc["k"].dtype))
-            kc["v"] = kc["v"].at[write_pages, write_rows].set(
-                v.astype(kc["v"].dtype))
-            o = attn.paged_prefill_attention(q, kc["k"], kc["v"],
-                                             block_tables, pos2d, p)
+            with jax.named_scope("kv_write"):
+                kc["k"] = kc["k"].at[write_pages, write_rows].set(
+                    k.astype(kc["k"].dtype))
+                kc["v"] = kc["v"].at[write_pages, write_rows].set(
+                    v.astype(kc["v"].dtype))
+            with jax.named_scope("attention"):
+                o = attn.paged_prefill_attention(q, kc["k"], kc["v"],
+                                                 block_tables, pos2d, p)
         o = mm_a(o.reshape(B, C, H * hd), lp["wo"], opts)
         if cfg.post_norms:
             o = _norm(o, lp["post_attn_norm"], cfg)
@@ -963,12 +995,11 @@ def prefill_chunk(params, cfg: ArchConfig, opts: ModelOpts, cache, tokens,
         return _maybe_quant_act(h, opts), kc
 
     x, cache_new = jax.lax.scan(
-        body, x, (params["layers"], windows, dict(cache)))
-    x = _norm_final(x, params, cfg)
-    last = x[jnp.arange(B), jnp.clip(last_idx, 0, C - 1)]  # (B, d)
-    logits = jnp.dot(last, materialize(_head_weight(params, cfg), last.dtype),
-                     preferred_element_type=jnp.float32)
-    logits = softcap(logits, cfg.final_logit_cap)
+        _scoped(body, "layer"), x, (params["layers"], windows, dict(cache)))
+    with jax.named_scope("head"):
+        x = _norm_final(x, params, cfg)
+        last = x[jnp.arange(B), jnp.clip(last_idx, 0, C - 1)]  # (B, d)
+        logits = _logits(params, cfg, last)
     return logits, cache_new
 
 
@@ -1000,9 +1031,10 @@ def decode_step(params, cfg: ArchConfig, opts: ModelOpts, cache, tokens,
         raise ValueError("quantized KV cache requires the paged layout")
     if paged:
         page = next(iter(cache.values())).shape[2]
-        write_page = jnp.take_along_axis(
-            block_tables, (positions // page)[:, None], axis=1)[:, 0]
-        write_row = positions % page
+        with jax.named_scope("kv_write"):
+            write_page = jnp.take_along_axis(
+                block_tables, (positions // page)[:, None], axis=1)[:, 0]
+            write_row = positions % page
 
     def body(h, inp):
         lp, window, kc = inp
@@ -1016,29 +1048,34 @@ def decode_step(params, cfg: ArchConfig, opts: ModelOpts, cache, tokens,
                             causal=True)
         kc = dict(kc)
         if quant:
-            k_st, k_mu, k_sig = kvq.quantize_kv(k[:, 0], opts.kv_bits)
-            v_st, v_mu, v_sig = kvq.quantize_kv(v[:, 0], opts.kv_bits)
-            for name, val in (("k_codes", k_st), ("k_mu", k_mu),
-                              ("k_sigma", k_sig), ("v_codes", v_st),
-                              ("v_mu", v_mu), ("v_sigma", v_sig)):
-                kc[name] = kc[name].at[write_page, write_row].set(
-                    val.astype(kc[name].dtype))
-            o = attn.paged_decode_attention_quant(q, kc, block_tables,
-                                                  positions, p,
-                                                  kv_bits=opts.kv_bits)
+            with jax.named_scope("kv_write"):
+                k_st, k_mu, k_sig = kvq.quantize_kv(k[:, 0], opts.kv_bits)
+                v_st, v_mu, v_sig = kvq.quantize_kv(v[:, 0], opts.kv_bits)
+                for name, val in (("k_codes", k_st), ("k_mu", k_mu),
+                                  ("k_sigma", k_sig), ("v_codes", v_st),
+                                  ("v_mu", v_mu), ("v_sigma", v_sig)):
+                    kc[name] = kc[name].at[write_page, write_row].set(
+                        val.astype(kc[name].dtype))
+            with jax.named_scope("attention"):
+                o = attn.paged_decode_attention_quant(
+                    q, kc, block_tables, positions, p, kv_bits=opts.kv_bits)
         elif paged:
-            kc["k"] = kc["k"].at[write_page, write_row].set(
-                k[:, 0].astype(kc["k"].dtype))
-            kc["v"] = kc["v"].at[write_page, write_row].set(
-                v[:, 0].astype(kc["v"].dtype))
-            o = attn.paged_decode_attention(q, kc["k"], kc["v"],
-                                            block_tables, positions, p)
+            with jax.named_scope("kv_write"):
+                kc["k"] = kc["k"].at[write_page, write_row].set(
+                    k[:, 0].astype(kc["k"].dtype))
+                kc["v"] = kc["v"].at[write_page, write_row].set(
+                    v[:, 0].astype(kc["v"].dtype))
+            with jax.named_scope("attention"):
+                o = attn.paged_decode_attention(q, kc["k"], kc["v"],
+                                                block_tables, positions, p)
         else:
-            kc["k"] = kc["k"].at[barange, positions].set(
-                k[:, 0].astype(kc["k"].dtype))
-            kc["v"] = kc["v"].at[barange, positions].set(
-                v[:, 0].astype(kc["v"].dtype))
-            o = attn.decode_attention(q, kc["k"], kc["v"], positions, p)
+            with jax.named_scope("kv_write"):
+                kc["k"] = kc["k"].at[barange, positions].set(
+                    k[:, 0].astype(kc["k"].dtype))
+                kc["v"] = kc["v"].at[barange, positions].set(
+                    v[:, 0].astype(kc["v"].dtype))
+            with jax.named_scope("attention"):
+                o = attn.decode_attention(q, kc["k"], kc["v"], positions, p)
         o = mm_a(o.reshape(B, 1, H * hd), lp["wo"], opts)
         if cfg.post_norms:
             o = _norm(o, lp["post_attn_norm"], cfg)
@@ -1047,9 +1084,8 @@ def decode_step(params, cfg: ArchConfig, opts: ModelOpts, cache, tokens,
         return _maybe_quant_act(h, opts), kc
 
     x, cache_new = jax.lax.scan(
-        body, x, (params["layers"], windows, dict(cache)))
-    x = _norm_final(x, params, cfg)
-    logits = jnp.dot(x[:, 0], materialize(_head_weight(params, cfg), x.dtype),
-                     preferred_element_type=jnp.float32)
-    logits = softcap(logits, cfg.final_logit_cap)
+        _scoped(body, "layer"), x, (params["layers"], windows, dict(cache)))
+    with jax.named_scope("head"):
+        x = _norm_final(x, params, cfg)
+        logits = _logits(params, cfg, x[:, 0])
     return logits, cache_new

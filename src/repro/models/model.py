@@ -146,12 +146,13 @@ def cache_insert(cache, prefill_cache, slots):
     """
     s_pad = prefill_cache["k"].shape[2]
     slots = jnp.asarray(slots, jnp.int32)
-    return {
-        "k": cache["k"].at[:, slots, :s_pad].set(
-            prefill_cache["k"].astype(cache["k"].dtype)),
-        "v": cache["v"].at[:, slots, :s_pad].set(
-            prefill_cache["v"].astype(cache["v"].dtype)),
-    }
+    with jax.named_scope("kv_write"):
+        return {
+            "k": cache["k"].at[:, slots, :s_pad].set(
+                prefill_cache["k"].astype(cache["k"].dtype)),
+            "v": cache["v"].at[:, slots, :s_pad].set(
+                prefill_cache["v"].astype(cache["v"].dtype)),
+        }
 
 
 def supports_paged_cache(cfg: ArchConfig) -> bool:

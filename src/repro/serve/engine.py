@@ -59,8 +59,10 @@ regime the paper's BOPs argument targets (EXPERIMENTS.md Sec. Perf).
 from __future__ import annotations
 
 import dataclasses
+import logging
+import re
 import time
-from typing import List, Optional, Sequence as Seq
+from typing import Dict, List, Optional, Sequence as Seq, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -75,7 +77,9 @@ from repro.serve.scheduler import (Request, SamplingParams, ScheduledSeq,
                                    Scheduler, Sequence, pages_for)
 
 __all__ = ["EngineConfig", "Engine", "Request", "SamplingParams",
-           "RequestOutput"]
+           "RequestOutput", "OP_SCOPES", "AMBIGUOUS", "hlo_op_scopes"]
+
+_log = logging.getLogger(__name__)
 
 # smallest bucketed decode batch: engines at or below this never bucket
 # (one compiled decode shape, exactly the pre-bucketing behavior), so the
@@ -102,9 +106,7 @@ class EngineConfig:
                                 #   matmuls (lm.mm_a, the qmatmul_a8
                                 #   regime) in BOTH prefill and decode;
                                 #   32 = full-precision activations.
-                                #   Surfaces in the metrics-snapshot meta,
-                                #   so traceview's BOPs attribution prices
-                                #   the precision actually served.
+                                #   Surfaces in the metrics-snapshot meta.
     pool_bytes: Optional[int] = None
     # byte budget for the page pool (alternative to total_pages): the pool
     # holds pool_bytes // page_kv_bytes(cfg, page_size, kv_bits) pages —
@@ -144,20 +146,19 @@ class EngineConfig:
     # launch/serve.py and benchmarks/engine_bench.py.
     telemetry: bool = True
     # structured observability (serve/telemetry.py, DESIGN.md Sec. 11):
-    # latency/queue histograms, occupancy gauges and per-step spans in a
-    # bounded ring buffer, exportable as a metrics snapshot + Chrome
-    # trace.  Host-side and O(1) per step; token streams are bit-
-    # identical on/off (pinned in tests) and the tok/s overhead is
-    # pinned in BENCH_engine.json.  False = the null object: same code
-    # path, records nothing.
+    # latency/queue histograms, occupancy gauges and the engine's phase
+    # spans in a bounded ring buffer, exportable as a metrics snapshot.
+    # Host-side and O(1) per step; token streams are bit-identical on/off
+    # (pinned in tests).  False = the null object: same code path,
+    # records nothing.
     trace_capacity: int = 65536
     # span/instant ring-buffer capacity; oldest whole spans drop first
-    # (the export never emits an orphaned half-span)
     profile_annotations: bool = False
-    # wrap the jitted steps in jax.profiler.TraceAnnotation so engine
-    # phases show up named inside device profiles (jax.profiler.trace /
-    # TensorBoard).  OFF by default: it adds a host-side annotation per
-    # call even when no profiler is attached.
+    # every engine span also opens a jax.profiler TraceAnnotation named
+    # engine.<span> (StepTraceAnnotation for engine.step), so the phases
+    # sit on the device profile's host clock (jax.profiler.trace,
+    # Perfetto).  OFF by default: about a microsecond per span even when
+    # no profiler is attached.
 
 
 @dataclasses.dataclass
@@ -195,6 +196,46 @@ def _fold_keys(seeds: jax.Array, positions: jax.Array) -> jax.Array:
     base = jax.random.PRNGKey(0)
     return jax.vmap(lambda s, p: jax.random.fold_in(
         jax.random.fold_in(base, s), p))(seeds, positions)
+
+
+def _sample(logits: jax.Array, seeds: jax.Array, positions: jax.Array,
+            temps: jax.Array, top_ks: jax.Array) -> jax.Array:
+    """``_sample_batch`` with keys folded at ``positions``, under the
+    ``sample`` scope."""
+    with jax.named_scope("sample"):
+        return _sample_batch(logits, _fold_keys(seeds, positions), temps,
+                             top_ks)
+
+
+# The named scopes the step programs open (models/lm.py and ``_sample``):
+# every op of the model's own computation carries one of them in its
+# op_name; ``dequant`` is always nested under ``matmul``.
+OP_SCOPES = ("layer", "matmul", "dequant", "attention", "kv_write", "embed",
+             "head", "sample")
+AMBIGUOUS = "?"     # one name, different scopes in two compiled buckets
+
+_HLO_MODULE = re.compile(r"^HloModule\s+([^\s,]+)")
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([^\s=%]+)\s*=\s")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def hlo_op_scopes(text: str) -> Tuple[str, Dict[str, str]]:
+    """(module name, {instruction name: innermost ``OP_SCOPES`` entry in
+    its op_name, or ""}) of a compiled module's HLO text, every
+    computation's instructions included."""
+    module, scopes = "", {}
+    for line in text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m is None:
+            if not module:
+                hm = _HLO_MODULE.match(line)
+                module = hm.group(1) if hm else module
+            continue
+        op = _HLO_OP_NAME.search(line)
+        parts = op.group(1).split("/") if op else ()
+        scopes[m.group(1)] = next(
+            (p for p in reversed(parts) if p in OP_SCOPES), "")
+    return module, scopes
 
 
 class Engine:
@@ -273,12 +314,24 @@ class Engine:
         self._util_tokens = 0
         self._util_page_tokens = 0
         self._last_decode_end: Optional[float] = None  # ITL anchor
+        self.n_steps = 0
+        # (program, batch bucket) -> (jitted fn, argument shapes) of each
+        # step program dispatched so far, for ``op_scopes``
+        self._dispatched: Dict[tuple, tuple] = {}
 
         # -- telemetry (DESIGN.md Sec. 11): histograms observed on the
         # hot path are O(1) bisects; everything state-shaped is a gauge
         # refreshed by the collector at snapshot time only
+        annotate = None
+        if ec.profile_annotations:
+            def annotate(name):
+                if name == "step":
+                    return jax.profiler.StepTraceAnnotation(
+                        "engine.step", step_num=self.n_steps)
+                return jax.profiler.TraceAnnotation("engine." + name)
         self.telemetry = tele_lib.Telemetry(enabled=ec.telemetry,
-                                            trace_capacity=ec.trace_capacity)
+                                            trace_capacity=ec.trace_capacity,
+                                            annotate=annotate)
         reg = self.telemetry.registry
         self._m_ttft = reg.histogram("ttft_s", help="arrival -> first token")
         self._m_itl = reg.histogram(
@@ -318,22 +371,19 @@ class Engine:
                         seeds):
             logits, cache = model.decode(params, cfg_, opts_, cache,
                                          tokens[:, None], positions)
-            keys = _fold_keys(seeds, positions)
-            return _sample_batch(logits, keys, temps, topks), cache
+            return _sample(logits, seeds, positions, temps, topks), cache
 
         def decode_paged(params, cache, tokens, positions, block_tables,
                          temps, topks, seeds):
             logits, cache = model.decode(params, cfg_, opts_, cache,
                                          tokens[:, None], positions,
                                          block_tables=block_tables)
-            keys = _fold_keys(seeds, positions)
-            return _sample_batch(logits, keys, temps, topks), cache
+            return _sample(logits, seeds, positions, temps, topks), cache
 
         def prefill_fn(params, tokens, last_idx, temps, topks, seeds):
             logits, kv = model.prefill(params, cfg_, opts_,
                                        {"tokens": tokens}, last_idx=last_idx)
-            keys = _fold_keys(seeds, last_idx)
-            return _sample_batch(logits, keys, temps, topks), kv
+            return _sample(logits, seeds, last_idx, temps, topks), kv
 
         def chunk_fn(params, cache, tokens, positions, write_pages,
                      write_rows, block_tables, last_idx, last_pos, temps,
@@ -344,8 +394,7 @@ class Engine:
             # fold at the prompt's absolute last position: the sampled
             # first token matches whole-prefill (and preempt/resume) bit
             # for bit, whichever chunking produced it
-            keys = _fold_keys(seeds, last_pos)
-            return _sample_batch(logits, keys, temps, topks), cache
+            return _sample(logits, seeds, last_pos, temps, topks), cache
 
         def copy_fn(cache, src, dst):
             return kv_cache.clone_pages(cache, src, dst)
@@ -371,28 +420,14 @@ class Engine:
             shim.lower = checked.lower
             return shim
 
-        def _annot(fn, name):
-            """With ec.profile_annotations, name the step inside device
-            profiles (jax.profiler.trace / TensorBoard) so engine phases
-            line up with the host-side Chrome trace spans."""
-            if not ec.profile_annotations:
-                return fn
-
-            def wrapped(*args):
-                with jax.profiler.TraceAnnotation(name):
-                    return fn(*args)
-            return wrapped
-
-        self._decode_jit = _jit(decode_paged if self.paged else decode_slot,
-                                donate=(1,))
-        self._decode_step = _annot(self._decode_jit, "engine.decode")
-        self._prefill_step = _annot(_jit(prefill_fn), "engine.prefill")
-        self._cache_insert = _annot(_jit(
+        self._decode_step = _jit(decode_paged if self.paged else decode_slot,
+                                 donate=(1,))
+        self._prefill_step = _jit(prefill_fn)
+        self._cache_insert = _jit(
             model.cache_insert_paged if self.paged else model.cache_insert,
-            donate=(0,)), "engine.cache_insert")
-        self._chunk_step = _annot(_jit(chunk_fn, donate=(1,)),
-                                  "engine.prefill_chunk")
-        self._copy_pages = _annot(_jit(copy_fn, donate=(0,)), "engine.cow")
+            donate=(0,))
+        self._chunk_step = _jit(chunk_fn, donate=(1,))
+        self._copy_pages = _jit(copy_fn, donate=(0,))
 
     def _collect_gauges(self) -> None:
         """Snapshot-time collector: mirror engine/scheduler state into the
@@ -484,8 +519,7 @@ class Engine:
 
     def config_meta(self) -> dict:
         """Engine-side facts for the metrics snapshot ``meta`` block (the
-        traceview attribution pass reconstructs cost models from these;
-        the driver adds what only it knows — w_bits, dist)."""
+        launcher adds what only it knows — w_bits, dist)."""
         ec, cfg = self.ec, self.cfg
         meta = {
             "arch": cfg.name, "family": cfg.family,
@@ -511,9 +545,41 @@ class Engine:
         m.update(meta or {})
         return self.telemetry.snapshot(m)
 
-    def chrome_trace(self) -> dict:
-        """Chrome-trace/Perfetto JSON of the recorded spans."""
-        return self.telemetry.tracer.to_chrome_trace()
+    def op_scopes(self) -> Dict[str, Dict[str, str]]:
+        """{program: {HLO instruction name: scope}} of every step program
+        and batch bucket dispatched so far, read from the compiled module
+        text of each (compiled already, so this costs a lowering and a
+        cache lookup per program).  A scope is the innermost ``OP_SCOPES``
+        entry in the instruction's op_name, "" when it has none (the
+        layer scan's own slicing and stacking, compiler-inserted copies);
+        a name two buckets of one program give different scopes is
+        ``AMBIGUOUS``.  Programs are named as the device profile's
+        ``XLA Modules`` line names them (``jit_decode_paged``)."""
+        t0 = time.perf_counter()
+        out: Dict[str, Dict[str, str]] = {}
+        for fn, args in self._dispatched.values():
+            module, scopes = hlo_op_scopes(
+                fn.lower(*args).compile().as_text())
+            merged = out.setdefault(module, {})
+            for name, scope in scopes.items():
+                merged[name] = scope if merged.get(name, scope) == scope \
+                    else AMBIGUOUS
+        _log.info("op_scopes: %d programs (%d compiled shapes) in %.3fs",
+                  len(out), len(self._dispatched), time.perf_counter() - t0)
+        return out
+
+    def _run(self, key: tuple, fn, lead: tuple, host: list):
+        """Upload the numpy inputs ``host`` and call ``fn(*lead, *host)``,
+        under the ``upload`` and ``dispatch`` spans.  The first call of
+        each ``key`` (program, batch bucket) keeps the argument shapes
+        for ``op_scopes``."""
+        with self.telemetry.span("upload"):
+            args = lead + tuple(jnp.asarray(a) for a in host)
+        if key not in self._dispatched:
+            self._dispatched[key] = (fn, jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args))
+        with self.telemetry.span("dispatch"):
+            return fn(*args)
 
     @property
     def kv_pool(self):
@@ -528,7 +594,7 @@ class Engine:
         if self.paged:
             args.append(self.scheduler.block_tables)
         args += [self._temps, self._topks, self._seeds]
-        return self._decode_jit.lower(*args)
+        return self._decode_step.lower(*args)
 
     @property
     def has_work(self) -> bool:
@@ -553,83 +619,98 @@ class Engine:
 
     # -- admission (batched prefill) ---------------------------------------
 
+    def _on_admit(self, seq: Sequence, now: float) -> None:
+        """Admission bookkeeping: the queue wait, and on the sequence's
+        first admission its ``queued`` span (arrival -> admission) on the
+        ``requests`` track, so a request still running has one."""
+        tele = self.telemetry
+        arrive = seq.request.arrival_time
+        tele.observe(self._m_queue_wait, now - (arrive or now))
+        if tele.enabled and seq.admit_time is None:
+            uid = seq.request.uid
+            tele.tracer.add_span("queued", now if arrive is None else arrive,
+                                 now, track="requests", tid=uid,
+                                 args={"uid": uid})
+        seq.admit_time = now
+
+    def _first_token(self, seq: Sequence, t: float) -> None:
+        """``seq`` sampled its first token at ``t``: TTFT and the
+        ``first_token`` instant (a resumed sequence keeps its first)."""
+        if seq.first_token_time is not None:
+            return
+        seq.first_token_time = t
+        self.n_prompt_tokens += int(seq.request.prompt.size)
+        tele = self.telemetry
+        tele.observe(self._m_ttft, t - (seq.request.arrival_time or t))
+        tele.instant("first_token", ts=t, track="requests",
+                     tid=seq.request.uid)
+
     def _admit(self, group: Seq[ScheduledSeq]) -> List[RequestOutput]:
-        now = time.perf_counter()
+        tele = self.telemetry
         G, P = len(group), self.ec.prefill_batch
         bucket = group[0].bucket
-        toks = np.zeros((P, bucket), np.int32)
-        last = np.zeros((P,), np.int32)
-        temps = np.zeros((P,), np.float32)
-        topks = np.zeros((P,), np.int32)
-        seeds = np.zeros((P,), np.int32)
-        slots = np.zeros((P,), np.int32)
-        prompts = [ss.seq.full_prompt for ss in group]
-        for i, ss in enumerate(group):
-            sp = ss.request.sampling
-            n = prompts[i].size
-            toks[i, :n] = prompts[i]
-            last[i] = n - 1
-            temps[i], topks[i], seeds[i] = sp.temperature, sp.top_k, sp.seed
-            slots[i] = ss.slot
-        if self.paged:
-            # padded rows keep all-zero page tables: their KV scatters into
-            # the reserved sink page, so the insert needs no masking and
-            # every bucket compiles exactly one (P, bucket) prefill.
-            rows = self.scheduler.page_table_rows(list(group), bucket)
-            page_tables = np.zeros((P, rows.shape[1]), np.int32)
-            page_tables[:G] = rows
-        else:
-            # pad rows beyond G with copies of row 0: identical KV scattered
-            # to the same slot is a harmless repeat write.
-            for i in range(G, P):
-                toks[i], last[i], slots[i] = toks[0], last[0], slots[0]
-
-        tele = self.telemetry
-        if tele.enabled:
+        with tele.span("prefill", batch=G, bucket=bucket) as sp:
+            now = time.perf_counter()
+            with tele.span("prepare"):
+                toks = np.zeros((P, bucket), np.int32)
+                last = np.zeros((P,), np.int32)
+                temps = np.zeros((P,), np.float32)
+                topks = np.zeros((P,), np.int32)
+                seeds = np.zeros((P,), np.int32)
+                slots = np.zeros((P,), np.int32)
+                prompts = [ss.seq.full_prompt for ss in group]
+                for i, ss in enumerate(group):
+                    spr = ss.request.sampling
+                    n = prompts[i].size
+                    toks[i, :n] = prompts[i]
+                    last[i] = n - 1
+                    temps[i], topks[i], seeds[i] = (spr.temperature,
+                                                    spr.top_k, spr.seed)
+                    slots[i] = ss.slot
+                if self.paged:
+                    # padded rows keep all-zero page tables: their KV
+                    # scatters into the reserved sink page, so the insert
+                    # needs no masking and every bucket compiles exactly
+                    # one (P, bucket) prefill.
+                    rows = self.scheduler.page_table_rows(list(group),
+                                                          bucket)
+                    dest = np.zeros((P, rows.shape[1]), np.int32)
+                    dest[:G] = rows
+                else:
+                    # pad rows beyond G with copies of row 0: identical KV
+                    # scattered to the same slot is a harmless repeat
+                    # write.
+                    for i in range(G, P):
+                        toks[i], last[i], slots[i] = toks[0], last[0], \
+                            slots[0]
+                    dest = slots
             for ss in group:
-                tele.observe(self._m_queue_wait,
-                             now - (ss.request.arrival_time or now))
-        first_tok, kv = self._prefill_step(self.params, jnp.asarray(toks),
-                                           jnp.asarray(last),
-                                           jnp.asarray(temps),
-                                           jnp.asarray(topks),
-                                           jnp.asarray(seeds))
-        if self.paged:
-            self._cache = self._cache_insert(self._cache, kv,
-                                             jnp.asarray(page_tables))
-        else:
-            self._cache = self._cache_insert(self._cache, kv,
-                                             jnp.asarray(slots))
-        self.n_prefill_calls += 1
-        self.n_prefill_tokens += int(sum(p.size for p in prompts[:G]))
-        first_np = np.asarray(first_tok)
-
-        finished: List[RequestOutput] = []
-        t_first = time.perf_counter()
+                self._on_admit(ss.seq, now)
+            first_tok, kv = self._run(("prefill", bucket),
+                                      self._prefill_step, (self.params,),
+                                      [toks, last, temps, topks, seeds])
+            self._cache = self._run(("cache_insert", bucket),
+                                    self._cache_insert, (self._cache, kv),
+                                    [dest])
+            self.n_prefill_calls += 1
+            self.n_prefill_tokens += int(sum(p.size for p in prompts[:G]))
+            with tele.span("sync"):
+                first_np = np.asarray(first_tok)
+            t_first = time.perf_counter()
+            for i, ss in enumerate(group):
+                seq = ss.seq
+                self._first_token(seq, t_first)
+                seq.generated.append(int(first_np[i]))
+                self._slots[ss.slot] = seq
+                spr = ss.request.sampling
+                self._positions[ss.slot] = prompts[i].size
+                self._cur_tok[ss.slot] = first_np[i]
+                self._temps[ss.slot] = spr.temperature
+                self._topks[ss.slot] = spr.top_k
+                self._seeds[ss.slot] = spr.seed
         if tele.enabled:
-            tele.observe(self._m_prefill_call, t_first - now)
-            tele.tracer.add_span("prefill", now, t_first,
-                                 args={"batch": G, "bucket": bucket})
-        for i, ss in enumerate(group):
-            seq = ss.seq
-            seq.admit_time = now
-            if seq.first_token_time is None:
-                seq.first_token_time = t_first
-                self.n_prompt_tokens += int(seq.request.prompt.size)
-                tele.observe(self._m_ttft,
-                             t_first - (ss.request.arrival_time or t_first))
-            seq.generated.append(int(first_np[i]))
-            self._slots[ss.slot] = seq
-            sp = ss.request.sampling
-            self._positions[ss.slot] = prompts[i].size
-            self._cur_tok[ss.slot] = first_np[i]
-            self._temps[ss.slot] = sp.temperature
-            self._topks[ss.slot] = sp.top_k
-            self._seeds[ss.slot] = sp.seed
-            done = self._finish_reason(ss.slot)
-            if done:
-                finished.append(self._complete(ss.slot, done))
-        return finished
+            tele.observe(self._m_prefill_call, sp.end - sp.start)
+        return self._complete_done([ss.slot for ss in group])
 
     # -- chunked prefill ---------------------------------------------------
 
@@ -652,8 +733,8 @@ class Engine:
         for i, (s, d) in enumerate(copies):
             src[i], dst[i] = s, d
         with self.telemetry.span("cow", n_copies=len(copies)):
-            self._cache = self._copy_pages(self._cache, jnp.asarray(src),
-                                           jnp.asarray(dst))
+            self._cache = self._run(("copy", n), self._copy_pages,
+                                    (self._cache,), [src, dst])
 
     def _advance_prefill_group(self, slots: List[int]) -> List[RequestOutput]:
         """Run one prompt chunk for a GROUP of mid-prefill sequences in a
@@ -668,27 +749,28 @@ class Engine:
         ``prefill_chunk_calls_saved`` counter tallies the saved calls.
         """
         tele = self.telemetry
-        t0 = tele.clock() if tele.enabled else 0.0
         # shared pages each chunk writes into must be copied first; a COW
         # preemption triggered by one slot can evict a peer from the group
         bounds: dict[int, tuple] = {}
-        for slot in slots:
-            if slot not in self._prefilling:
-                continue
-            seq = self._prefilling[slot]
-            a = seq.prefill_progress
-            b = min(a + self.chunk_tokens, seq.full_prompt.size)
-            for vslot, vseq in self.scheduler.prepare_chunk_writes(
-                    slot, a, b):
-                tele.instant("preempt", track="requests",
-                             tid=vseq.request.uid,
-                             args={"by": seq.request.uid, "cause": "cow"})
-                self._clear_slot(vslot)
-            # apply per slot (not once for the group): a later prepare may
-            # preempt an earlier slot and recycle its fresh COW dst pages,
-            # so batching the pairs could alias two copies onto one dst
-            self._apply_cow()
-            bounds[slot] = (a, b)
+        with tele.span("schedule"):
+            for slot in slots:
+                if slot not in self._prefilling:
+                    continue
+                seq = self._prefilling[slot]
+                a = seq.prefill_progress
+                b = min(a + self.chunk_tokens, seq.full_prompt.size)
+                for vslot, vseq in self.scheduler.prepare_chunk_writes(
+                        slot, a, b):
+                    tele.instant("preempt", track="requests",
+                                 tid=vseq.request.uid,
+                                 args={"by": seq.request.uid, "cause": "cow"})
+                    self._clear_slot(vslot)
+                # apply per slot (not once for the group): a later prepare
+                # may preempt an earlier slot and recycle its fresh COW dst
+                # pages, so batching the pairs could alias two copies onto
+                # one dst
+                self._apply_cow()
+                bounds[slot] = (a, b)
         live = [s for s in slots if s in self._prefilling and s in bounds]
         if not live:
             return []
@@ -696,6 +778,56 @@ class Engine:
         Bp = 1                              # power-of-two batch bucket:
         while Bp < G:                       # compile count stays O(log
             Bp *= 2                         # max_slots), not O(traffic)
+        n_valid = sum(bounds[s][1] - bounds[s][0] for s in live)
+        finished: List[RequestOutput] = []
+        with tele.span("prefill_chunk", batch=G, tokens=n_valid) as sp:
+            with tele.span("prepare"):
+                host = self._chunk_inputs(live, bounds, Bp)
+            tok, self._cache = self._run(("chunk", Bp), self._chunk_step,
+                                         (self.params, self._cache), host)
+            self.n_prefill_calls += 1
+            self.n_prefill_tokens += n_valid
+            if G > 1:
+                self._m_chunk_saved.inc(G - 1)
+            with tele.span("sync"):
+                tok_np = np.asarray(tok)
+            for i, slot in enumerate(live):
+                seq = self._prefilling[slot]
+                prompt = seq.full_prompt
+                _, b = bounds[slot]
+                seq.prefill_progress = b
+                if b < prompt.size:
+                    continue
+                # final chunk: publish the full prompt pages, activate the
+                # slot
+                self.scheduler.on_prefill_complete(slot)
+                seq.prefill_progress = None
+                del self._prefilling[slot]
+                first = int(tok_np[i])
+                spr = seq.request.sampling
+                self._first_token(seq, time.perf_counter())
+                seq.generated.append(first)
+                self._slots[slot] = seq
+                self._positions[slot] = prompt.size
+                self._cur_tok[slot] = first
+                self._temps[slot] = spr.temperature
+                self._topks[slot] = spr.top_k
+                self._seeds[slot] = spr.seed
+                # completed in place: the scheduler sees the same order of
+                # registrations and releases as a B=1 call per slot
+                finished.extend(self._complete_done([slot]))
+        if tele.enabled:
+            tele.observe(self._m_chunk_call, sp.end - sp.start)
+            if self._slots:
+                # decode was live while this chunk ran: interleaved
+                # prefill work, the decode-stall currency
+                self._m_tok_prefill_step.inc(n_valid)
+        return finished
+
+    def _chunk_inputs(self, live: List[int], bounds: dict,
+                      Bp: int) -> list:
+        """The numpy inputs of one coalesced ``prefill_chunk`` call for
+        the slots ``live`` (rows beyond them are sink-page padding)."""
         C = self.chunk_tokens
         page = self.ec.page_size
         tables_all = np.asarray(self.scheduler.block_tables)
@@ -709,13 +841,11 @@ class Engine:
         temps = np.zeros((Bp,), np.float32)
         topks = np.zeros((Bp,), np.int32)
         seeds = np.zeros((Bp,), np.int32)
-        n_valid = 0
         for i, slot in enumerate(live):
             seq = self._prefilling[slot]
             prompt = seq.full_prompt
             a, b = bounds[slot]
             valid = b - a
-            n_valid += valid
             toks[i, :valid] = prompt[a:b]
             positions[i] = a + np.arange(C)
             row = tables_all[slot]
@@ -727,121 +857,76 @@ class Engine:
             last_pos[i] = prompt.size - 1
             temps[i], topks[i], seeds[i] = (sp.temperature, sp.top_k,
                                             sp.seed)
-        tok, self._cache = self._chunk_step(
-            self.params, self._cache, jnp.asarray(toks),
-            jnp.asarray(positions), jnp.asarray(write_pages),
-            jnp.asarray(write_rows), jnp.asarray(tables),
-            jnp.asarray(last_idx), jnp.asarray(last_pos),
-            jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(seeds))
-        self.n_prefill_calls += 1
-        self.n_prefill_tokens += n_valid
-        if G > 1:
-            self._m_chunk_saved.inc(G - 1)
-        tok_np = np.asarray(tok)
-        if tele.enabled:
-            t1 = tele.clock()
-            tele.observe(self._m_chunk_call, t1 - t0)
-            tele.tracer.add_span("prefill_chunk", t0, t1,
-                                 args={"batch": G, "tokens": n_valid})
-            if self._slots:
-                # decode was live while this chunk ran: interleaved
-                # prefill work, the decode-stall currency
-                self._m_tok_prefill_step.inc(n_valid)
-        finished: List[RequestOutput] = []
-        for i, slot in enumerate(live):
-            seq = self._prefilling[slot]
-            prompt = seq.full_prompt
-            _, b = bounds[slot]
-            seq.prefill_progress = b
-            if b < prompt.size:
-                continue
-            # final chunk: publish the full prompt pages, activate the slot
-            self.scheduler.on_prefill_complete(slot)
-            seq.prefill_progress = None
-            del self._prefilling[slot]
-            first = int(tok_np[i])
-            sp = seq.request.sampling
-            if seq.first_token_time is None:
-                seq.first_token_time = time.perf_counter()
-                self.n_prompt_tokens += int(seq.request.prompt.size)
-                tele.observe(self._m_ttft, seq.first_token_time
-                             - (seq.request.arrival_time
-                                or seq.first_token_time))
-            seq.generated.append(first)
-            self._slots[slot] = seq
-            self._positions[slot] = prompt.size
-            self._cur_tok[slot] = first
-            self._temps[slot] = sp.temperature
-            self._topks[slot] = sp.top_k
-            self._seeds[slot] = sp.seed
-            done = self._finish_reason(slot)
-            if done:
-                finished.append(self._complete(slot, done))
-        return finished
+        return [toks, positions, write_pages, write_rows, tables, last_idx,
+                last_pos, temps, topks, seeds]
 
     # -- decode ------------------------------------------------------------
 
+    def _decode_inputs(self):
+        """(batch bucket, numpy inputs after params and pool, row of each
+        active slot or None when rows are slots) of the decode step."""
+        if not self.paged:
+            return self.ec.max_slots, [
+                self._cur_tok, self._positions, self._temps, self._topks,
+                self._seeds], None
+        self._util_tokens += self.scheduler.tokens_in_use
+        self._util_page_tokens += (self.scheduler.pages_in_use
+                                   * self.ec.page_size)
+        active = sorted(self._slots)
+        Bb = self._decode_bucket(len(active))
+        if self.ec.bucket_decode and active and Bb < self.ec.max_slots:
+            # gather the active rows into the bucket; pad rows carry
+            # zero block tables, so their scatter lands in the sink
+            # page exactly like an inactive slot's in the full batch
+            bt = np.asarray(self.scheduler.block_tables)
+            rows = np.asarray(active, np.int32)
+            host = [np.zeros(Bb, self._cur_tok.dtype),
+                    np.zeros(Bb, self._positions.dtype),
+                    np.zeros((Bb, bt.shape[1]), bt.dtype),
+                    np.zeros(Bb, self._temps.dtype),
+                    np.zeros(Bb, self._topks.dtype),
+                    np.zeros(Bb, self._seeds.dtype)]
+            for dst, src in zip(host, (self._cur_tok, self._positions, bt,
+                                       self._temps, self._topks,
+                                       self._seeds)):
+                dst[:rows.size] = src[rows]
+            self.n_bucketed_steps += 1
+            return Bb, host, {slot: i for i, slot in enumerate(active)}
+        block_tables = self.scheduler.block_tables
+        if self._prefilling:
+            # mid-prefill slots are inactive in the decode step, but it
+            # still scatters their (zero) row-0 write — point those rows
+            # at the sink so real (possibly shared) pages are never
+            # touched
+            block_tables = block_tables.copy()
+            block_tables[list(self._prefilling)] = 0
+        return self.ec.max_slots, [
+            self._cur_tok, self._positions, block_tables, self._temps,
+            self._topks, self._seeds], None
+
     def _decode_active(self) -> List[RequestOutput]:
         tele = self.telemetry
-        t0 = tele.clock() if tele.enabled else 0.0
         n_active = len(self._slots)
-        row_of = None
-        if self.paged:
-            self._util_tokens += self.scheduler.tokens_in_use
-            self._util_page_tokens += (self.scheduler.pages_in_use
-                                       * self.ec.page_size)
-            active = sorted(self._slots)
-            Bb = self._decode_bucket(len(active))
-            if self.ec.bucket_decode and active and Bb < self.ec.max_slots:
-                # gather the active rows into the bucket; pad rows carry
-                # zero block tables, so their scatter lands in the sink
-                # page exactly like an inactive slot's in the full batch
-                bt = np.asarray(self.scheduler.block_tables)
-                rows = np.asarray(active, np.int32)
-                toks = np.zeros(Bb, self._cur_tok.dtype)
-                pos = np.zeros(Bb, self._positions.dtype)
-                tabs = np.zeros((Bb, bt.shape[1]), bt.dtype)
-                temps = np.zeros(Bb, self._temps.dtype)
-                topks = np.zeros(Bb, self._topks.dtype)
-                seeds = np.zeros(Bb, self._seeds.dtype)
-                n = rows.size
-                toks[:n] = self._cur_tok[rows]
-                pos[:n] = self._positions[rows]
-                tabs[:n] = bt[rows]
-                temps[:n] = self._temps[rows]
-                topks[:n] = self._topks[rows]
-                seeds[:n] = self._seeds[rows]
-                next_tok, self._cache = self._decode_step(
-                    self.params, self._cache, jnp.asarray(toks),
-                    jnp.asarray(pos), jnp.asarray(tabs), jnp.asarray(temps),
-                    jnp.asarray(topks), jnp.asarray(seeds))
-                row_of = {slot: i for i, slot in enumerate(active)}
-                self.n_bucketed_steps += 1
-            else:
-                block_tables = self.scheduler.block_tables
-                if self._prefilling:
-                    # mid-prefill slots are inactive in the decode step,
-                    # but it still scatters their (zero) row-0 write —
-                    # point those rows at the sink so real (possibly
-                    # shared) pages are never touched
-                    block_tables = block_tables.copy()
-                    block_tables[list(self._prefilling)] = 0
-                next_tok, self._cache = self._decode_step(
-                    self.params, self._cache, jnp.asarray(self._cur_tok),
-                    jnp.asarray(self._positions),
-                    jnp.asarray(block_tables),
-                    jnp.asarray(self._temps), jnp.asarray(self._topks),
-                    jnp.asarray(self._seeds))
-        else:
-            next_tok, self._cache = self._decode_step(
-                self.params, self._cache, jnp.asarray(self._cur_tok),
-                jnp.asarray(self._positions), jnp.asarray(self._temps),
-                jnp.asarray(self._topks), jnp.asarray(self._seeds))
-        self.n_decode_steps += 1
-        next_np = np.asarray(next_tok)       # host sync: the step is done
+        advanced: List[int] = []
+        with tele.span("decode", batch=n_active) as sp:
+            with tele.span("prepare"):
+                bucket, host, row_of = self._decode_inputs()
+            next_tok, self._cache = self._run(
+                ("decode", bucket), self._decode_step,
+                (self.params, self._cache), host)
+            self.n_decode_steps += 1
+            with tele.span("sync") as sync:
+                next_np = np.asarray(next_tok)   # the step is done
+            for slot in list(self._slots):
+                seq = self._slots[slot]
+                tok = next_np[slot if row_of is None else row_of[slot]]
+                seq.generated.append(int(tok))
+                self._positions[slot] += 1
+                self._cur_tok[slot] = tok
+                advanced.append(slot)
         if tele.enabled:
-            t1 = tele.clock()
-            tele.observe(self._m_decode_step, t1 - t0)
+            t1 = sync.end
+            tele.observe(self._m_decode_step, t1 - sp.start)
             tele.observe(self._m_batch, n_active)
             self._m_tok_decode.inc(n_active)
             if self._last_decode_end is not None:
@@ -850,19 +935,7 @@ class Engine:
                 # which is exactly what a waiting client experiences
                 tele.observe(self._m_itl, t1 - self._last_decode_end)
             self._last_decode_end = t1
-            tele.tracer.add_span("decode", t0, t1,
-                                 args={"batch": n_active})
-        finished: List[RequestOutput] = []
-        for slot in list(self._slots):
-            seq = self._slots[slot]
-            tok = next_np[slot if row_of is None else row_of[slot]]
-            seq.generated.append(int(tok))
-            self._positions[slot] += 1
-            self._cur_tok[slot] = tok
-            done = self._finish_reason(slot)
-            if done:
-                finished.append(self._complete(slot, done))
-        return finished
+        return self._complete_done(advanced)
 
     def _decode_bucket(self, n_active: int) -> int:
         """Power-of-two decode batch bucket for an active-slot count:
@@ -892,6 +965,16 @@ class Engine:
         self._topks[slot] = 0
         self._seeds[slot] = 0
 
+    def _complete_done(self, slots: List[int]) -> List[RequestOutput]:
+        """Complete, in order, those of ``slots`` whose sequence has
+        finished, under one ``complete`` span."""
+        done = [(slot, r) for slot in slots
+                if (r := self._finish_reason(slot))]
+        if not done:
+            return []
+        with self.telemetry.span("complete", n=len(done)):
+            return [self._complete(slot, r) for slot, r in done]
+
     def _complete(self, slot: int, reason: str) -> RequestOutput:
         seq = self._slots[slot]
         self.scheduler.complete(slot, evicted=(reason == "evicted"))
@@ -911,19 +994,16 @@ class Engine:
 
     def _emit_lifecycle(self, seq: Sequence, arrive: float, finish: float,
                         reason: str) -> None:
-        """Request-lifecycle spans on the ``requests`` track (tid = uid):
-        queued [arrival, admit], prefill [admit, first token], decode
-        [first token, finish].  Emitted whole at completion, so a ring
-        eviction can only drop a whole request's lane, never half of
-        one.  After preempt/resume, admit/first reflect the *last*
-        admission; the preempt ``instant`` markers in between tell the
-        story (args carry the round-trip count)."""
+        """Request-lifecycle spans on the ``requests`` track (tid = uid),
+        written at completion: prefill [admit, first token], decode
+        [first token, finish].  (``queued`` is written at the first
+        admission and ``first_token`` when it is sampled.)  After
+        preempt/resume, admit/first reflect the *last* admission; the
+        preempt ``instant`` markers in between tell the story."""
         tr = self.telemetry.tracer
         uid = seq.request.uid
         admit = min(max(seq.admit_time or arrive, arrive), finish)
         first = min(max(seq.first_token_time or finish, admit), finish)
-        tr.add_span("queued", arrive, admit, track="requests", tid=uid,
-                    args={"uid": uid})
         tr.add_span("prefill", admit, first, track="requests", tid=uid,
                     args={"prompt_tokens": int(seq.request.prompt.size),
                           "cache_hit_tokens": seq.cache_hit_tokens})
@@ -939,61 +1019,62 @@ class Engine:
         (chunked mode only claims slots/pages — compute is spread over
         later steps), advance one prompt chunk per mid-prefill slot,
         grow/preempt/copy pages for the coming decode writes (paged
-        mode), then advance all active slots one decode step."""
+        mode), then advance all active slots one decode step.
+
+        Spans (``step`` and its children): ``schedule`` (admission,
+        prefix-cache lookup, page growth, preemption), ``prefill`` /
+        ``prefill_chunk`` and ``decode`` (each split into ``prepare``,
+        ``upload``, ``dispatch`` and ``sync``), ``cow``, ``complete``."""
         tele = self.telemetry
-        t_step = tele.clock() if tele.enabled else 0.0
+        self.n_steps += 1
         finished: List[RequestOutput] = []
-        while True:
-            group = self.scheduler.schedule()
-            if not group:
-                break
-            if self.chunked:
-                now = time.perf_counter()
-                for ss in group:
-                    tele.observe(self._m_queue_wait,
-                                 now - (ss.request.arrival_time or now))
-                    ss.seq.admit_time = now
-                    ss.seq.prefill_progress = ss.seq.cache_hit_tokens
-                    self._prefilling[ss.slot] = ss.seq
+        with tele.span("step"):
+            while True:
+                with tele.span("schedule"):
+                    group = self.scheduler.schedule()
+                    if group and self.chunked:
+                        now = time.perf_counter()
+                        for ss in group:
+                            self._on_admit(ss.seq, now)
+                            ss.seq.prefill_progress = ss.seq.cache_hit_tokens
+                            self._prefilling[ss.slot] = ss.seq
+                if not group:
+                    break
+                if not self.chunked:
+                    finished.extend(self._admit(group))
+            if self._prefilling:
+                # one chunk for EVERY mid-prefill slot, oldest first: the
+                # decode stall per step stays bounded by
+                # n_prefilling * chunk_tokens (the chunk size is the policy
+                # knob), while a whole admission wave advances together
+                # instead of serializing one sequence per step
+                order = sorted(self._prefilling,
+                               key=lambda s: self._prefilling[s].order)
+                if self.ec.coalesce_prefill:
+                    # ...and the whole wave shares ONE batched chunk call
+                    finished.extend(self._advance_prefill_group(order))
+                else:
+                    for slot in order:
+                        if slot in self._prefilling:  # not preempted by a peer
+                            finished.extend(
+                                self._advance_prefill_group([slot]))
+            if self.paged and self._slots:
+                with tele.span("schedule"):
+                    for slot, seq in self.scheduler.ensure_decode_pages(
+                            writing=set(self._slots)):
+                        # sequence went back to the waiting queue with its
+                        # tokens; only the device-side slot state is dropped
+                        tele.instant("preempt", track="requests",
+                                     tid=seq.request.uid,
+                                     args={"cause": "pool_exhausted"})
+                        self._clear_slot(slot)
+                self._apply_cow()
+            if self._slots:
+                finished.extend(self._decode_active())
             else:
-                finished.extend(self._admit(group))
-        if self._prefilling:
-            # one chunk for EVERY mid-prefill slot, oldest first: the
-            # decode stall per step stays bounded by
-            # n_prefilling * chunk_tokens (the chunk size is the policy
-            # knob), while a whole admission wave advances together
-            # instead of serializing one sequence per step
-            order = sorted(self._prefilling,
-                           key=lambda s: self._prefilling[s].order)
-            if self.ec.coalesce_prefill:
-                # ...and the whole wave shares ONE batched chunk call
-                finished.extend(self._advance_prefill_group(order))
-            else:
-                for slot in order:
-                    if slot in self._prefilling:  # not preempted by a peer
-                        finished.extend(self._advance_prefill_group([slot]))
-        if self.paged and self._slots:
-            for slot, seq in self.scheduler.ensure_decode_pages(
-                    writing=set(self._slots)):
-                # sequence went back to the waiting queue with its tokens;
-                # only the device-side slot state is dropped here
-                tele.instant("preempt", track="requests",
-                             tid=seq.request.uid,
-                             args={"cause": "pool_exhausted"})
-                self._clear_slot(slot)
-            self._apply_cow()
-        if self._slots:
-            finished.extend(self._decode_active())
-        else:
-            # no decode ran: the next sampled token's gap is not an
-            # inter-token latency (the stream was idle or pure-prefill)
-            self._last_decode_end = None
-        if tele.enabled:
-            tele.tracer.add_span(
-                "step", t_step, tele.clock(),
-                args={"running": len(self._slots),
-                      "prefilling": len(self._prefilling),
-                      "waiting": self.scheduler.n_waiting})
+                # no decode ran: the next sampled token's gap is not an
+                # inter-token latency (the stream was idle or pure-prefill)
+                self._last_decode_end = None
         return finished
 
     def generate(self, requests: Seq[Request]) -> List[RequestOutput]:

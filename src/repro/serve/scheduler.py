@@ -119,7 +119,7 @@ class Sequence:
     order: int                            # submission index = FCFS priority
     generated: List[int] = dataclasses.field(default_factory=list)
     first_token_time: Optional[float] = None
-    admit_time: float = 0.0
+    admit_time: Optional[float] = None    # latest admission
     n_preempts: int = 0
     prefill_progress: Optional[int] = None
     cache_hit_tokens: int = 0

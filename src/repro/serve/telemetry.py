@@ -1,8 +1,6 @@
 """Structured observability for the serving stack (DESIGN.md Sec. 11).
 
-The engine's perf story used to live in an ad-hoc ``stats()`` dict and
-scattered ``time.perf_counter()`` deltas; this module replaces that with
-three small, dependency-free primitives:
+Three small, dependency-free primitives:
 
   * a **metrics registry** — named counters, gauges and fixed-bucket
     histograms with percentile estimation, exported as a stable JSON
@@ -10,15 +8,15 @@ three small, dependency-free primitives:
     state (pool occupancy, queue depth) are refreshed by *collector*
     callbacks at snapshot time, so the hot path never pays for them.
   * a **span tracer** — a bounded in-memory ring buffer of completed
-    spans and instant events on named (process, thread) tracks,
-    exportable as Chrome-trace / Perfetto JSON (``chrome://tracing``).
-    Spans are recorded *complete* (start + duration), so the export can
-    always emit matched B/E pairs — a ring-buffer eviction can drop a
-    whole span but never orphan half of one.
+    spans and instant events on named (track, tid) lanes.  Spans opened
+    as context managers nest and record their parent, so a phase's self
+    time (``self_times``) is its duration less its children's.
   * a **Telemetry** bundle tying the two together behind one ``enabled``
     flag, with null-object behavior when disabled: every method stays
     callable and O(1), records nothing, and the engine's device work is
-    bit-identical either way (pinned by tests/test_telemetry.py).
+    bit-identical either way (pinned by tests/test_telemetry.py).  An
+    optional annotation factory puts every span on the JAX profiler's
+    clock as well (the engine's ``profile_annotations``).
 
 Hot-path contract: this module is **host-only** (pure stdlib — no jax;
 enforced by uniqcheck rule UQ106) and every per-step operation is
@@ -36,12 +34,13 @@ import math
 import re
 import time
 from collections import deque
-from typing import Callable, Dict, Iterator, List, Optional, Tuple
+from typing import (Callable, ContextManager, Dict, Iterable, Iterator,
+                    List, Optional, Tuple)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "Span", "Instant", "Tracer", "Telemetry", "NULL_TELEMETRY",
-    "time_buckets", "linear_buckets",
+    "self_times", "time_buckets", "linear_buckets",
 ]
 
 
@@ -288,13 +287,19 @@ class MetricsRegistry:
 
 @dataclasses.dataclass(frozen=True)
 class Span:
-    """A completed span: ``[start, start + dur)`` seconds on a track."""
+    """A completed span: ``[start, start + dur)`` seconds on a track.
+
+    ``sid`` is unique within its tracer; ``parent`` is the ``sid`` of the
+    span that was open around this one when it opened (None at the top
+    level, and for spans written after the fact with ``add_span``)."""
     name: str
     start: float
     dur: float
     track: str = "engine"
     tid: int = 0
     args: Optional[Dict] = None
+    sid: int = 0
+    parent: Optional[int] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -307,21 +312,38 @@ class Instant:
 
 
 class _SpanCtx:
-    """Context manager recording one span on exit (O(1))."""
-    __slots__ = ("_tracer", "_name", "_track", "_tid", "_args", "_t0")
+    """Context manager recording one span on exit (O(1)); ``start`` and
+    ``end`` hold its clock readings once it has closed.  An
+    ``annotation`` (any context manager) is entered around it."""
+    __slots__ = ("_tracer", "_name", "_track", "_tid", "_args", "_ann",
+                 "_sid", "_parent", "start", "end")
 
-    def __init__(self, tracer, name, track, tid, args):
+    def __init__(self, tracer, name, track, tid, args, annotation=None):
         self._tracer, self._name = tracer, name
         self._track, self._tid, self._args = track, tid, args
+        self._ann = annotation
 
     def __enter__(self):
-        self._t0 = self._tracer.clock()
+        if self._ann is not None:
+            self._ann.__enter__()
+        tr = self._tracer
+        tr._next_sid += 1
+        self._sid = tr._next_sid
+        self._parent = tr._open[-1] if tr._open else None
+        tr._open.append(self._sid)
+        self.start = tr.clock()
         return self
 
     def __exit__(self, *exc):
-        t1 = self._tracer.clock()
-        self._tracer.add_span(self._name, self._t0, t1, self._track,
-                              self._tid, self._args)
+        tr = self._tracer
+        self.end = tr.clock()
+        tr._open.pop()
+        tr._record(Span(self._name, self.start, max(self.end - self.start,
+                                                    0.0),
+                        self._track, self._tid, self._args, self._sid,
+                        self._parent))
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -337,16 +359,14 @@ class _NullCtx:
 
 _NULL_CTX = _NullCtx()
 
-# stable pid per track name in the Chrome export (alphabetical extras)
-_TRACK_PIDS = {"engine": 1, "requests": 2}
-
 
 class Tracer:
-    """Bounded ring buffer of spans/instants with Chrome-trace export.
+    """Bounded ring buffer of spans and instants.
 
     All timestamps are ``clock()`` seconds (``time.perf_counter`` —
-    monotonic); the export rebases on the tracer's epoch and converts to
-    integer microseconds, the Chrome trace event format's unit.
+    monotonic).  Spans opened with ``span()`` nest: each records the span
+    open around it, so ``self_times`` can subtract children.  One tracer
+    serves one thread.
     """
 
     def __init__(self, capacity: int = 65536,
@@ -354,24 +374,30 @@ class Tracer:
         if capacity < 1:
             raise ValueError("capacity must be >= 1")
         self.clock = clock
-        self.epoch = clock()
         self._spans: deque = deque(maxlen=capacity)
         self._instants: deque = deque(maxlen=capacity)
+        self._open: List[int] = []      # sids of the spans open now
+        self._next_sid = 0
         self.n_spans_total = 0       # including ring-evicted
         self.n_instants_total = 0
 
     # -- recording ---------------------------------------------------------
 
     def span(self, name: str, track: str = "engine", tid: int = 0,
-             **args) -> _SpanCtx:
-        return _SpanCtx(self, name, track, tid, args or None)
+             annotation=None, **args) -> _SpanCtx:
+        return _SpanCtx(self, name, track, tid, args or None, annotation)
+
+    def _record(self, span: Span) -> None:
+        self._spans.append(span)
+        self.n_spans_total += 1
 
     def add_span(self, name: str, start: float, end: float,
                  track: str = "engine", tid: int = 0,
                  args: Optional[Dict] = None) -> None:
-        self._spans.append(Span(name, start, max(end - start, 0.0),
-                                track, tid, args))
-        self.n_spans_total += 1
+        """Record a span measured elsewhere (no parent)."""
+        self._next_sid += 1
+        self._record(Span(name, start, max(end - start, 0.0), track, tid,
+                          args, self._next_sid))
 
     def instant(self, name: str, ts: Optional[float] = None,
                 track: str = "engine", tid: int = 0,
@@ -390,79 +416,36 @@ class Tracer:
     def spans(self) -> Iterator[Span]:
         return iter(self._spans)
 
+    def instants(self) -> Iterator[Instant]:
+        return iter(self._instants)
+
     def reset(self) -> None:
         self._spans.clear()
         self._instants.clear()
         self.n_spans_total = 0
         self.n_instants_total = 0
-        self.epoch = self.clock()
 
-    # -- export ------------------------------------------------------------
 
-    def _us(self, t: float) -> int:
-        return int(round((t - self.epoch) * 1e6))
-
-    def to_chrome_trace(self) -> Dict:
-        """Chrome trace event format (``chrome://tracing`` / Perfetto).
-
-        Duration events are emitted as matched B/E pairs per
-        (pid, tid).  Spans on one track may interleave arbitrarily in
-        the ring; the export rebuilds proper nesting with a stack —
-        a child's E always precedes its parent's E, and a child that
-        outlives its parent is clamped to the parent's end (the engine
-        only produces well-nested spans, so clamping is a no-op there).
-        """
-        events: List[Dict] = []
-        pids: Dict[str, int] = {}
-
-        def pid_of(track: str) -> int:
-            if track not in pids:
-                pids[track] = _TRACK_PIDS.get(
-                    track, 100 + len([t for t in pids
-                                      if t not in _TRACK_PIDS]))
-                events.append({"name": "process_name", "ph": "M",
-                               "pid": pids[track], "tid": 0,
-                               "args": {"name": track}})
-            return pids[track]
-
-        by_lane: Dict[Tuple[str, int], List[Span]] = {}
-        for s in self._spans:
-            by_lane.setdefault((s.track, s.tid), []).append(s)
-
-        for (track, tid), spans in sorted(by_lane.items()):
-            pid = pid_of(track)
-            # parents before children at equal start
-            spans.sort(key=lambda s: (s.start, -s.dur))
-            stack: List[Tuple[float, int]] = []     # (end, idx into evts)
-            for s in spans:
-                start = s.start
-                while stack and stack[-1][0] <= start + 1e-12:
-                    end, _ = stack.pop()
-                    events.append({"name": "", "ph": "E", "pid": pid,
-                                   "tid": tid, "ts": self._us(end)})
-                end = s.start + s.dur
-                if stack:
-                    end = min(end, stack[-1][0])    # clamp to parent
-                    start = max(start, 0.0)
-                ev = {"name": s.name, "ph": "B", "pid": pid, "tid": tid,
-                      "ts": self._us(start)}
-                if s.args:
-                    ev["args"] = dict(s.args)
-                events.append(ev)
-                stack.append((end, len(events) - 1))
-            while stack:
-                end, _ = stack.pop()
-                events.append({"name": "", "ph": "E", "pid": pid,
-                               "tid": tid, "ts": self._us(end)})
-        for i in self._instants:
-            ev = {"name": i.name, "ph": "i", "s": "t",
-                  "pid": pid_of(i.track), "tid": i.tid,
-                  "ts": self._us(i.ts)}
-            if i.args:
-                ev["args"] = dict(i.args)
-            events.append(ev)
-        return {"traceEvents": events, "displayTimeUnit": "ms",
-                "otherData": {"dropped_events": self.n_dropped}}
+def self_times(spans: Iterable[Span]) -> Dict[int, float]:
+    """``sid`` -> the span's duration minus the part of its interval that
+    its children (spans whose ``parent`` is it) cover."""
+    spans = list(spans)
+    children: Dict[int, List[Tuple[float, float]]] = {}
+    for s in spans:
+        if s.parent is not None:
+            children.setdefault(s.parent, []).append(
+                (s.start, s.start + s.dur))
+    out: Dict[int, float] = {}
+    for s in spans:
+        lo, hi = s.start, s.start + s.dur
+        covered, reach = 0.0, lo
+        for a, b in sorted(children.get(s.sid, ())):
+            a, b = max(a, reach), min(b, hi)
+            if b > a:
+                covered += b - a
+                reach = b
+        out[s.sid] = s.dur - covered
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -475,20 +458,27 @@ class Telemetry:
     Disabled, every call is still valid and O(1) but records nothing —
     the engine keeps exactly one code path, and the on/off token-stream
     parity is structural (telemetry never touches device data).
+
+    ``annotate(name)``, when given, returns a context manager that
+    ``span()`` enters around the span — the engine passes
+    ``jax.profiler`` annotations, which put its spans on the profiler's
+    clock.  It applies to spans whether or not the ring is enabled.
     """
 
     def __init__(self, enabled: bool = True, trace_capacity: int = 65536,
-                 clock: Callable[[], float] = time.perf_counter):
+                 clock: Callable[[], float] = time.perf_counter,
+                 annotate: Optional[Callable[[str], ContextManager]] = None):
         self.enabled = enabled
         self.registry = MetricsRegistry()
         self.tracer = Tracer(trace_capacity, clock)
-        self.clock = clock
+        self.annotate = annotate
 
     def span(self, name: str, track: str = "engine", tid: int = 0,
              **args):
+        ann = self.annotate(name) if self.annotate is not None else None
         if not self.enabled:
-            return _NULL_CTX
-        return self.tracer.span(name, track, tid, **args)
+            return _NULL_CTX if ann is None else ann
+        return self.tracer.span(name, track, tid, ann, **args)
 
     def instant(self, name: str, **kw) -> None:
         if self.enabled:
@@ -520,10 +510,6 @@ class Telemetry:
             json.dump(self.snapshot(meta), fh, indent=2, sort_keys=True)
         with open(path + ".prom", "w") as fh:
             fh.write(self.registry.to_prometheus())
-
-    def write_chrome_trace(self, path: str) -> None:
-        with open(path, "w") as fh:
-            json.dump(self.tracer.to_chrome_trace(), fh)
 
 
 # shared disabled instance for call sites with no telemetry wired in
