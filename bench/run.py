@@ -19,8 +19,12 @@ A run, in one process that holds the chip:
            the traffic's lead: the window opens once the load is steady
   window   ``--seconds`` of traffic through ``Engine.submit`` / ``step``,
            timed by the host clock around each step; with ``--trace 1`` the
-           profiler records it, and the per-layer metrics are read instead
-           of the end-to-end ones
+           profiler records it (its Python tracer off), and the per-layer
+           metrics are read instead of the end-to-end ones
+  drain    turns due in the window get ``MAX_DRAIN_S`` of engine steps to
+           give their first token, from the window's end, or in a traced
+           run from the end of the profiler's teardown; one with none by
+           the drain's end is ``failed``
   check    the program's state freed, a sample of finished requests drawn
            from the seed is run through the plain reference
            (``bench/reference.py``) and its widest served-token gap is held
@@ -48,7 +52,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 import types  # noqa: E402
-from typing import Dict, List, Optional  # noqa: E402
+from typing import Dict, List, Optional, Tuple  # noqa: E402
 
 BENCH = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -58,7 +62,7 @@ import numpy as np  # noqa: E402
 
 from bench import traffic, work  # noqa: E402
 
-MAX_DRAIN_S = 60.0          # a turn due in the window may answer this late
+MAX_DRAIN_S = 60.0          # engine time a turn due in the window may take
 
 
 def log(msg: str) -> None:
@@ -395,6 +399,7 @@ class Window:
     decode_steps: int
     counters: Dict[str, int]
     compiles: int
+    drain: Tuple[float, float] = (0.0, 0.0)     # the drain's start, end
 
 
 def counters(eng) -> Dict[str, int]:
@@ -414,7 +419,10 @@ def counters(eng) -> Dict[str, int]:
 def run_traffic(drv: Driver, mix: dict, seconds: float, compile_log,
                 trace_dir: Optional[str]):
     """Lead, window, and the drain that lets turns due in the window
-    answer.  Returns the Window."""
+    answer.  The drain runs the engine for at most ``MAX_DRAIN_S`` from
+    its start: the window's end, or in a traced run the end of the
+    profiler's teardown, which is the harness's time and not the engine's.
+    Returns the Window."""
     import jax
     drv.start()
     lead = mix["lead"]
@@ -432,7 +440,12 @@ def run_traffic(drv: Driver, mix: dict, seconds: float, compile_log,
     ws = t1
     c0 = counters(drv.eng)
     if trace_dir:
-        jax.profiler.start_trace(trace_dir)
+        # the host tracer keeps every TraceAnnotation (the engine.* and
+        # bench.* spans); the Python tracer, which records every host frame,
+        # is off: it added host time to each step and swelled the trace
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
     annot = jax.profiler.TraceAnnotation("bench.window")
     annot.__enter__()
     while True:                                         # the window
@@ -453,18 +466,29 @@ def run_traffic(drv: Driver, mix: dict, seconds: float, compile_log,
     we = t1
     annot.__exit__(None, None, None)
     if trace_dir:
+        t_stop = time.perf_counter()
         jax.profiler.stop_trace()
+        log(f"[trace] stop_trace {time.perf_counter() - t_stop:.2f}s")
     c1 = counters(drv.eng)
+    drain_start = time.perf_counter() if trace_dir else we
     win = Window(ws, we, c1["decode_steps"] - c0["decode_steps"],
                  {k: c1[k] - c0[k] for k in c1},
                  compile_log.between(ws, we))
+    waiting = drv.first_tokens_pending(ws, we)
     while drv.first_tokens_pending(ws, we) \
-            and time.perf_counter() < we + MAX_DRAIN_S:   # the drain
+            and time.perf_counter() < drain_start + MAX_DRAIN_S:  # the drain
         drv.submit_due(time.perf_counter())
         if drv.eng.has_work:
             drv.step()
         else:
             drv.idle_until_due()
+    win.drain = (drain_start, time.perf_counter())
+    latest = max((t for due, t in drv.first_tokens if ws <= due < we),
+                 default=we)
+    log(f"[drain] {win.drain[0] - we:+.3f}s to {win.drain[1] - we:+.3f}s "
+        f"after the window's end: {waiting} turns due in the window waited "
+        f"for a first token, {drv.first_tokens_pending(ws, we)} still do; "
+        f"the latest first token came {latest - we:+.3f}s after the end")
     return win
 
 
@@ -556,11 +580,15 @@ def main(argv=None, require=require_chips,
     due = [st for st, _, _ in drv.done if win.start <= st.due < win.end] + \
         [st for st in drv.live.values() if win.start <= st.due < win.end]
     result["attempted"] = len(due)
-    result["failed"] = len(drv.failed) + sum(
-        1 for st in drv.live.values()
-        if win.start <= st.due < win.end and st.first is None)
+    unanswered = {uid: st for uid, st in drv.live.items()
+                  if win.start <= st.due < win.end and st.first is None}
+    result["failed"] = len(drv.failed) + len(unanswered)
     for f in drv.failed[:5]:
         log(f"[failed] {f}")
+    for uid, st in unanswered.items():
+        log(f"[failed] uid {uid}: no first token; due {st.due - win.end:+.3f}s"
+            f", drain {win.drain[0] - win.end:+.3f}s to "
+            f"{win.drain[1] - win.end:+.3f}s, from the window's end")
     units = {m["name"]: m["unit"] for m in cell.end_to_end + cell.per_layer}
     if args.trace:
         from bench import trace

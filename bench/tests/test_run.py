@@ -6,20 +6,22 @@ TPU."""
 
 import json
 import os
+import re
 import subprocess
 import sys
+import time
 
 import jax
 import numpy as np
 import pytest
 
-from bench import run
+from bench import run, trace
 from bench.tests.conftest import ROOT, cpu_devices
 
 
-def _run(root, cell, seed, *extra):
+def _run(root, cell, seed, *extra, traced=0):
     return run.main(["--workload", cell, "--seed", str(seed), "--seconds",
-                     "1.5", "--trace", "0", *extra],
+                     "1.5", "--trace", str(traced), *extra],
                     require=cpu_devices, root=root)
 
 
@@ -78,3 +80,77 @@ def test_the_harness_refuses_a_machine_without_a_tpu():
     assert p.returncode != 0
     assert p.stdout.strip() == ""
     assert "needs a TPU" in p.stderr
+
+
+def _cpu_loader(host_names):
+    """Stands in for ``trace.load`` on the CPU, whose trace has no TPU plane:
+    the Python thread's host events as ``trace.load`` reads them (their names
+    are kept in ``host_names``), and the CPU executor threads' events as the
+    device's ops."""
+    def load(path, device=0):
+        from jax.profiler import ProfileData
+        host, ops = [], []
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name != "/host:CPU":
+                continue
+            for line in plane.lines:
+                (host if line.name.startswith("python") else ops).extend(
+                    (e.start_ns * 1e-9, e.end_ns * 1e-9, e.name)
+                    for e in line.events)
+        host_names.extend(n for _, _, n in host)
+        return trace.DeviceTrace(sorted(ops), [], sorted(host))
+    return load
+
+
+@pytest.mark.parametrize("answer_in_drain", [True, False])
+def test_the_profilers_teardown_is_not_charged_to_the_drain(
+        smoke_root, monkeypatch, capsys, answer_in_drain):
+    """A traced run whose ``stop_trace`` outlasts ``MAX_DRAIN_S``, after a
+    window whose engine makes no progress in its last 0.8 s, so that turns
+    submitted then wait through the teardown: they answer in the drain that
+    follows it.  With the engine's steps still no-ops in the drain they
+    never answer, and the gate still counts them ``failed``."""
+    from repro.serve.engine import Engine
+    host_names, opened, torn_down = [], [], []
+    start, stop, step = (jax.profiler.start_trace, jax.profiler.stop_trace,
+                         Engine.step)
+    v5e = run.work.peaks("TPU v5 lite")
+
+    def start_trace(*args, **kwargs):
+        opened.append(time.perf_counter())
+        return start(*args, **kwargs)
+
+    def slow_stop():
+        stop()
+        time.sleep(1.5)
+        torn_down.append(True)
+
+    def stalling_step(self):
+        stalled = opened and time.perf_counter() > opened[0] + 0.7
+        if stalled and not (torn_down and answer_in_drain):
+            return []
+        return step(self)
+    monkeypatch.setattr(trace, "load", _cpu_loader(host_names))
+    monkeypatch.setattr(run.work, "peaks", lambda kind: v5e)
+    monkeypatch.setattr(run, "MAX_DRAIN_S", 1.0)
+    monkeypatch.setattr(jax.profiler, "start_trace", start_trace)
+    monkeypatch.setattr(jax.profiler, "stop_trace", slow_stop)
+    monkeypatch.setattr(Engine, "step", stalling_step)
+    res = _run(smoke_root, "smoke.sessions", 2 ** 31 + 29, traced=1)
+    err = capsys.readouterr().err
+    assert re.search(r"^\[trace\] stop_trace \d+\.\d\ds$", err, re.M)
+    drain = re.search(r"^\[drain\] \+(\S+)s to \+(\S+)s after the window's "
+                      r"end: (\d+) turns due", err, re.M)
+    begin, end, waiting = float(drain[1]), float(drain[2]), int(drain[3])
+    assert begin >= 1.5 and waiting > 0     # the teardown held turns back
+    if answer_in_drain:
+        assert res["correct"] and res["failed"] == 0, res
+        assert end < begin + 1.0
+    else:
+        assert not res["correct"] and res["failed"] > 0, res
+        assert res["check"]["failed"] == {"value": res["failed"], "limit": 0}
+        assert end >= begin + 1.0
+        assert "no first token" in err
+    # the trace read: the engine's and the harness's spans, no Python frames
+    assert not [n for n in host_names if n.startswith("$")]
+    assert {"bench.window", "engine.step"} <= set(host_names)

@@ -166,3 +166,17 @@ def test_recorded_idle_split_accounts_for_every_idle_second(recorded):
     assert not [k for k in s.seconds if k.startswith("$")]
     assert _read("idle_engine_ms_per_step", r) == pytest.approx(
         1e3 * s.of("engine.") / 10)
+
+
+@pytest.mark.parametrize("name", READERS)
+def test_recorded_readers_read_the_same_without_python_frames(recorded, name):
+    """The harness traces with the profiler's Python tracer off: its ``$``
+    frames (most of the recorded trace's host events) feed no reader."""
+    tr, scope_map, counts = recorded
+    bare = trace.DeviceTrace(tr.ops, tr.modules,
+                             [h for h in tr.host if not h[2].startswith("$")])
+    assert len(bare.host) < len(tr.host) / 10
+    full, without = (_read(name, _reading(t, scope_map, counts["decode_steps"],
+                                          counts["prefill_tokens"]))
+                     for t in (tr, bare))
+    assert full is not None and without == full
