@@ -140,31 +140,35 @@ def chunked_attention(q: Array, k: Array, v: Array, q_pos: Array,
     return out.reshape(B, Sq, H, D).astype(q.dtype)
 
 
-def paged_decode_attention(q: Array, k_pages: Array, v_pages: Array,
-                           block_tables: Array, q_pos: Array,
+def paged_decode_attention(q: Array, k_pool: Array, v_pool: Array,
+                           layer: Array, block_tables: Array, q_pos: Array,
                            p: AttnParams) -> Array:
-    """Decode attention against a paged KV pool.
+    """Decode attention against one layer of a paged KV pool.
 
     q            : (B, 1, H, D) current-position queries.
-    k/v_pages    : (P, page, KV, D) device-resident page pool (all slots
-                   share it; a sequence's KV lives in the pages its block
-                   table names, page j covering positions
-                   [j*page, (j+1)*page)).
+    k/v_pool     : (L, P, page, KV, D) device-resident page pool of every
+                   layer (all slots share it; a sequence's KV lives in
+                   the pages its block table names, page j covering
+                   positions [j*page, (j+1)*page)).
+    layer        : () int32 layer whose pages are read.
     block_tables : (B, n_pages) int32 page ids per sequence; entries past
                    the allocated prefix point at the reserved sink page 0
                    and are masked out by position below.
     q_pos        : (B,) current positions.
 
-    The gathered view is position-contiguous by construction, so the
-    plain masked ``decode_attention`` applies unchanged: keys at
-    positions > q_pos (never-written or sink rows) are masked to -inf
-    exactly as out-of-prefix rows are in the slot cache.
+    One gather ``k_pool[layer, block_tables]`` reads the table's pages of
+    that layer straight from the whole pool (the decode scan carries it),
+    so no per-layer slice is materialized.  The gathered view is
+    position-contiguous by construction, so the plain masked
+    ``decode_attention`` applies unchanged: keys at positions > q_pos
+    (never-written or sink rows) are masked to -inf exactly as
+    out-of-prefix rows are in the slot cache.
     """
     B = q.shape[0]
-    _, page, KV, D = k_pages.shape
+    _, _, page, KV, D = k_pool.shape
     n_pages = block_tables.shape[1]
-    k = k_pages[block_tables].reshape(B, n_pages * page, KV, D)
-    v = v_pages[block_tables].reshape(B, n_pages * page, KV, D)
+    k = k_pool[layer, block_tables].reshape(B, n_pages * page, KV, D)
+    v = v_pool[layer, block_tables].reshape(B, n_pages * page, KV, D)
     return decode_attention(q, k, v, q_pos, p)
 
 
@@ -176,10 +180,12 @@ def paged_decode_attention_quant(q: Array, cache, block_tables: Array,
     """Decode attention against a k-quantile-coded paged KV pool.
 
     q            : (B, 1, H, D) current-position queries.
-    cache        : per-layer slice of the quantized pool —
+    cache        : one layer of the quantized pool —
                    {"k_codes","v_codes"} (P, page, KV, D') int8/uint8 and
                    {"k_mu","k_sigma","v_mu","v_sigma"} (P, page, KV) bf16
                    (see models/kv_cache.py; D' = D//2 packed for 4-bit).
+                   The decode scan passes ``pool[l]`` of the pool it
+                   carries: a read of that layer, never stacked back.
     block_tables : (B, n_pages) int32 page ids; sink-page entries are
                    masked out by position exactly as in the dense path.
 
@@ -220,32 +226,37 @@ def paged_decode_attention_quant(q: Array, cache, block_tables: Array,
     return decode_attention(q, k, v, q_pos, p)
 
 
-def paged_prefill_attention(q: Array, k_pages: Array, v_pages: Array,
-                            block_tables: Array, q_pos: Array,
+def paged_prefill_attention(q: Array, k_pool: Array, v_pool: Array,
+                            layer: Array, block_tables: Array, q_pos: Array,
                             p: AttnParams) -> Array:
-    """Multi-token (chunked) prefill attention against a paged KV pool.
+    """Multi-token (chunked) prefill attention against one layer of a
+    paged KV pool.
 
     q            : (B, C, H, D) one chunk of prompt queries.
-    k/v_pages    : (P, page, KV, D) page pool; the chunk's own rows must
-                   already be scattered in (write-before-read).
+    k/v_pool     : (L, P, page, KV, D) page pool of every layer; the
+                   chunk's own rows must already be written in
+                   (write-before-read).
+    layer        : () int32 layer whose pages are read.
     block_tables : (B, n_pages) page ids; sink entries masked by position.
     q_pos        : (C,) absolute positions of the chunk's tokens, or
                    (B, C) per-sequence positions when several coalesced
                    sequences' chunks sit at different prompt offsets.
 
-    The gathered view is position-contiguous (page j of the table covers
-    positions [j*page, (j+1)*page)), so ``full_attention``'s causal
+    One gather ``k_pool[layer, block_tables]`` reads the layer's pages
+    from the whole pool, as in ``paged_decode_attention``.  The gathered
+    view is position-contiguous (page j of the table covers positions
+    [j*page, (j+1)*page)), so ``full_attention``'s causal
     ``k_pos <= q_pos`` mask makes the chunk see exactly the rows a whole
     prefill of the same prefix would: earlier chunks' pages, plus this
     chunk's freshly written rows; later rows (other sequences' content in
     a partially-shared page, sink garbage) are masked to -inf.
     """
     B = q.shape[0]
-    _, page, KV, D = k_pages.shape
+    _, _, page, KV, D = k_pool.shape
     n_pages = block_tables.shape[1]
     Sk = n_pages * page
-    k = k_pages[block_tables].reshape(B, Sk, KV, D)
-    v = v_pages[block_tables].reshape(B, Sk, KV, D)
+    k = k_pool[layer, block_tables].reshape(B, Sk, KV, D)
+    v = v_pool[layer, block_tables].reshape(B, Sk, KV, D)
     k_pos = jnp.arange(Sk, dtype=jnp.int32)
     return full_attention(q, k.astype(q.dtype), v.astype(q.dtype),
                           q_pos, k_pos, p)

@@ -896,6 +896,37 @@ def cache_insert_paged(cache, prefill_cache, page_tables):
                 for name in cache}
 
 
+def _scan_pool(body, x, layers, windows, pool):
+    """Run a step program's layer ``body`` over every layer with the KV
+    pool in the scan's carry.
+
+    ``body((h, pool), (lp, window, l)) -> (h, pool)`` writes layer ``l``'s
+    fresh rows in place (``pool[name].at[l, ...]``) and reads its pages
+    straight from the carried pool.  No layer's slice is restacked into a
+    fresh buffer, so a donated pool is updated where it lies."""
+    layer_ids = jnp.arange(windows.shape[0], dtype=jnp.int32)
+    (x, pool), _ = jax.lax.scan(
+        _scoped(lambda carry, inp: (body(carry, inp), None), "layer"),
+        (x, pool), (layers, windows, layer_ids))
+    return x, pool
+
+
+def _write_kv(pool, l, index, k, v, kv_bits: int):
+    """Write layer ``l``'s fresh K/V rows ``k``/``v`` (..., KV, hd) into
+    the carried pool at ``[l, *index]``: dense ``k``/``v`` leaves, or, for
+    a coded pool, each row's codes and statistics (models/kv_cache.py)."""
+    with jax.named_scope("kv_write"):
+        if kvq.is_quantized_cache(pool):
+            k_st, k_mu, k_sig = kvq.quantize_kv(k, kv_bits)
+            v_st, v_mu, v_sig = kvq.quantize_kv(v, kv_bits)
+            rows = {"k_codes": k_st, "k_mu": k_mu, "k_sigma": k_sig,
+                    "v_codes": v_st, "v_mu": v_mu, "v_sigma": v_sig}
+        else:
+            rows = {"k": k, "v": v}
+        return {name: pool[name].at[(l, *index)].set(
+                    rows[name].astype(pool[name].dtype)) for name in pool}
+
+
 def prefill_chunk(params, cfg: ArchConfig, opts: ModelOpts, cache, tokens,
                   positions, write_pages, write_rows, block_tables,
                   last_idx):
@@ -927,10 +958,10 @@ def prefill_chunk(params, cfg: ArchConfig, opts: ModelOpts, cache, tokens,
     ever read under the causal mask, contributing exact zeros), and
     sampling folds by (seed, absolute position) — never batch shape.
 
-    Each layer scatters the chunk's fresh KV (codes + stats when
-    ``opts.kv_bits < 16``) into the pool *before* attending, then attends
-    over the gathered block-table row under the causal mask — the same
-    write-before-read discipline as ``decode_step``, so a chunk sees
+    Each layer writes the chunk's fresh KV (codes + stats when
+    ``opts.kv_bits < 16``) into the carried pool *before* attending, then
+    attends over the gathered block-table row under the causal mask — the
+    same write-before-read discipline as ``decode_step``, so a chunk sees
     earlier chunks' pages (including prefix-cache hits) plus its own rows,
     and produces bit-identical codes to a whole prefill of the same
     prompt: a row's codes depend only on that row's K/V, attention inputs
@@ -952,11 +983,11 @@ def prefill_chunk(params, cfg: ArchConfig, opts: ModelOpts, cache, tokens,
         write_rows = jnp.asarray(write_rows, jnp.int32)
     last_idx = jnp.atleast_1d(jnp.asarray(last_idx, jnp.int32))
     pos2d = positions
-    windows = _window_schedule(cfg)
     quant = kvq.is_quantized_cache(cache)
 
-    def body(h, inp):
-        lp, window, kc = inp
+    def body(carry, inp):
+        h, pool = carry
+        lp, window, l = inp
         hn = _norm(h, lp["attn_norm"], cfg)
         q = mm_a(hn, lp["wq"], opts).reshape(B, C, H, hd)
         k = mm_a(hn, lp["wk"], opts).reshape(B, C, KV, hd)
@@ -965,37 +996,25 @@ def prefill_chunk(params, cfg: ArchConfig, opts: ModelOpts, cache, tokens,
         k = apply_rope(k, pos2d, cfg.rope_theta)
         p = attn.AttnParams(window=window, logit_cap=cfg.attn_logit_cap,
                             causal=True)
-        kc = dict(kc)
-        if quant:
-            with jax.named_scope("kv_write"):
-                k_st, k_mu, k_sig = kvq.quantize_kv(k, opts.kv_bits)
-                v_st, v_mu, v_sig = kvq.quantize_kv(v, opts.kv_bits)
-                for name, val in (("k_codes", k_st), ("k_mu", k_mu),
-                                  ("k_sigma", k_sig), ("v_codes", v_st),
-                                  ("v_mu", v_mu), ("v_sigma", v_sig)):
-                    kc[name] = kc[name].at[write_pages, write_rows].set(
-                        val.astype(kc[name].dtype))
-            with jax.named_scope("attention"):
+        pool = _write_kv(pool, l, (write_pages, write_rows), k, v,
+                         opts.kv_bits)
+        with jax.named_scope("attention"):
+            if quant:
                 o = attn.paged_prefill_attention_quant(
-                    q, kc, block_tables, pos2d, p, kv_bits=opts.kv_bits)
-        else:
-            with jax.named_scope("kv_write"):
-                kc["k"] = kc["k"].at[write_pages, write_rows].set(
-                    k.astype(kc["k"].dtype))
-                kc["v"] = kc["v"].at[write_pages, write_rows].set(
-                    v.astype(kc["v"].dtype))
-            with jax.named_scope("attention"):
-                o = attn.paged_prefill_attention(q, kc["k"], kc["v"],
-                                                 block_tables, pos2d, p)
+                    q, {n: a[l] for n, a in pool.items()}, block_tables,
+                    pos2d, p, kv_bits=opts.kv_bits)
+            else:
+                o = attn.paged_prefill_attention(
+                    q, pool["k"], pool["v"], l, block_tables, pos2d, p)
         o = mm_a(o.reshape(B, C, H * hd), lp["wo"], opts)
         if cfg.post_norms:
             o = _norm(o, lp["post_attn_norm"], cfg)
         h = h + o
         h = h + _ffn_block(h, lp, cfg, opts)
-        return _maybe_quant_act(h, opts), kc
+        return _maybe_quant_act(h, opts), pool
 
-    x, cache_new = jax.lax.scan(
-        _scoped(body, "layer"), x, (params["layers"], windows, dict(cache)))
+    x, cache_new = _scan_pool(body, x, params["layers"],
+                              _window_schedule(cfg), dict(cache))
     with jax.named_scope("head"):
         x = _norm_final(x, params, cfg)
         last = x[jnp.arange(B), jnp.clip(last_idx, 0, C - 1)]  # (B, d)
@@ -1013,9 +1032,13 @@ def decode_step(params, cfg: ArchConfig, opts: ModelOpts, cache, tokens,
     ``block_tables`` (B, n_pages) int32 page indirection otherwise.
 
     Quantized pages (``opts.kv_bits < 16``): the step codes the fresh
-    K/V row per (row, head), scatters codes + stats into the pool, then
+    K/V row per (row, head), writes codes + stats into the pool, then
     attends through the fused gather+unpack+dequant paged path — the
     row's own code is written before it is read, matching prefill.
+
+    The pool rides in the layer scan's carry (``_scan_pool``): each layer
+    writes its B fresh rows in place and reads its pages from the carried
+    pool, so a donated pool is updated where it lies.
 
     Returns (logits (B, V), updated cache).
     """
@@ -1023,8 +1046,6 @@ def decode_step(params, cfg: ArchConfig, opts: ModelOpts, cache, tokens,
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     x = _embed_tokens(params, cfg, opts, tokens)          # (B, 1, d)
     pos2d = positions[:, None]
-    windows = _window_schedule(cfg)
-    barange = jnp.arange(B)
     paged = block_tables is not None
     quant = kvq.is_quantized_cache(cache)
     if quant and not paged:
@@ -1034,10 +1055,13 @@ def decode_step(params, cfg: ArchConfig, opts: ModelOpts, cache, tokens,
         with jax.named_scope("kv_write"):
             write_page = jnp.take_along_axis(
                 block_tables, (positions // page)[:, None], axis=1)[:, 0]
-            write_row = positions % page
+            write_at = (write_page, positions % page)
+    else:
+        write_at = (jnp.arange(B), positions)
 
-    def body(h, inp):
-        lp, window, kc = inp
+    def body(carry, inp):
+        h, pool = carry
+        lp, window, l = inp
         hn = _norm(h, lp["attn_norm"], cfg)
         q = mm_a(hn, lp["wq"], opts).reshape(B, 1, H, hd)
         k = mm_a(hn, lp["wk"], opts).reshape(B, 1, KV, hd)
@@ -1046,45 +1070,27 @@ def decode_step(params, cfg: ArchConfig, opts: ModelOpts, cache, tokens,
         k = apply_rope(k, pos2d, cfg.rope_theta)
         p = attn.AttnParams(window=window, logit_cap=cfg.attn_logit_cap,
                             causal=True)
-        kc = dict(kc)
-        if quant:
-            with jax.named_scope("kv_write"):
-                k_st, k_mu, k_sig = kvq.quantize_kv(k[:, 0], opts.kv_bits)
-                v_st, v_mu, v_sig = kvq.quantize_kv(v[:, 0], opts.kv_bits)
-                for name, val in (("k_codes", k_st), ("k_mu", k_mu),
-                                  ("k_sigma", k_sig), ("v_codes", v_st),
-                                  ("v_mu", v_mu), ("v_sigma", v_sig)):
-                    kc[name] = kc[name].at[write_page, write_row].set(
-                        val.astype(kc[name].dtype))
-            with jax.named_scope("attention"):
+        pool = _write_kv(pool, l, write_at, k[:, 0], v[:, 0], opts.kv_bits)
+        with jax.named_scope("attention"):
+            if quant:
                 o = attn.paged_decode_attention_quant(
-                    q, kc, block_tables, positions, p, kv_bits=opts.kv_bits)
-        elif paged:
-            with jax.named_scope("kv_write"):
-                kc["k"] = kc["k"].at[write_page, write_row].set(
-                    k[:, 0].astype(kc["k"].dtype))
-                kc["v"] = kc["v"].at[write_page, write_row].set(
-                    v[:, 0].astype(kc["v"].dtype))
-            with jax.named_scope("attention"):
-                o = attn.paged_decode_attention(q, kc["k"], kc["v"],
-                                                block_tables, positions, p)
-        else:
-            with jax.named_scope("kv_write"):
-                kc["k"] = kc["k"].at[barange, positions].set(
-                    k[:, 0].astype(kc["k"].dtype))
-                kc["v"] = kc["v"].at[barange, positions].set(
-                    v[:, 0].astype(kc["v"].dtype))
-            with jax.named_scope("attention"):
-                o = attn.decode_attention(q, kc["k"], kc["v"], positions, p)
+                    q, {n: a[l] for n, a in pool.items()}, block_tables,
+                    positions, p, kv_bits=opts.kv_bits)
+            elif paged:
+                o = attn.paged_decode_attention(
+                    q, pool["k"], pool["v"], l, block_tables, positions, p)
+            else:
+                o = attn.decode_attention(q, pool["k"][l], pool["v"][l],
+                                          positions, p)
         o = mm_a(o.reshape(B, 1, H * hd), lp["wo"], opts)
         if cfg.post_norms:
             o = _norm(o, lp["post_attn_norm"], cfg)
         h = h + o
         h = h + _ffn_block(h, lp, cfg, opts)
-        return _maybe_quant_act(h, opts), kc
+        return _maybe_quant_act(h, opts), pool
 
-    x, cache_new = jax.lax.scan(
-        _scoped(body, "layer"), x, (params["layers"], windows, dict(cache)))
+    x, cache_new = _scan_pool(body, x, params["layers"],
+                              _window_schedule(cfg), dict(cache))
     with jax.named_scope("head"):
         x = _norm_final(x, params, cfg)
         logits = _logits(params, cfg, x[:, 0])

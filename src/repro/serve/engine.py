@@ -551,7 +551,7 @@ class Engine:
         text of each (compiled already, so this costs a lowering and a
         cache lookup per program).  A scope is the innermost ``OP_SCOPES``
         entry in the instruction's op_name, "" when it has none (the
-        layer scan's own slicing and stacking, compiler-inserted copies);
+        layer scan's slicing of the weights, compiler-inserted copies);
         a name two buckets of one program give different scopes is
         ``AMBIGUOUS``.  Programs are named as the device profile's
         ``XLA Modules`` line names them (``jit_decode_paged``)."""
