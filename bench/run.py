@@ -7,8 +7,11 @@
 The cell, its configuration (``bench/configs/<config>.json``) and its traffic
 mix (``bench/traffic/<mix>.json``, with ``<mix>.py`` where the mix brings a
 generator of its own) are found by name from ``BENCHMARK.json``; the per-layer
-metrics' readers are ``bench/layers/<metric>.py``.  Nothing here names a
-cell.
+metrics' readers are ``bench/layers/<metric>.py``.  The configuration file
+names its plain reference (``reference``) and the counts of its work
+(``work``), each a Python file of the checkout, and may set fields of the
+program's ArchConfig (``arch_fields``).  Nothing here names a cell or an
+architecture.
 
 A run, in one process that holds the chip:
 
@@ -26,9 +29,9 @@ A run, in one process that holds the chip:
            run from the end of the profiler's teardown; one with none by
            the drain's end is ``failed``
   check    the program's state freed, a sample of finished requests drawn
-           from the seed is run through the plain reference
-           (``bench/reference.py``) and its widest served-token gap is held
-           to the configuration's limit
+           from the seed is run through the plain reference the
+           configuration names and its widest served-token gap is held to
+           the configuration's limit
 
 The last line of stdout is one JSON object; the numbers compared are also
 the last lines of stderr.  On anything but a TPU it exits nonzero before
@@ -48,6 +51,7 @@ import heapq  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import pathlib  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -79,6 +83,8 @@ class Cell:
     generate: object        # sessions(mix, seed, vocab, n) of the mix
     end_to_end: List[dict]
     per_layer: List[dict]
+    reference: types.ModuleType     # the configuration's ``reference``
+    work: types.ModuleType          # the configuration's ``work``
 
 
 def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
@@ -96,17 +102,53 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     return Cell(w, config, traffic.load(w["traffic"], mixes),
                 traffic.generator(w["traffic"], mixes),
                 [m for m in spec["end_to_end"] if mine(m)],
-                [m for m in spec["per_layer"] if mine(m)])
+                [m for m in spec["per_layer"] if mine(m)],
+                config_module(config, "reference", root),
+                config_module(config, "work", root))
+
+
+def load_file(path: pathlib.Path, key: str) -> types.ModuleType:
+    """The Python file ``path``, run as module ``key``."""
+    spec = importlib.util.spec_from_file_location(key, path)
+    mod = sys.modules[key] = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def layer_reader(metric: str, directory: pathlib.Path = BENCH / "layers"):
     """``read(run) -> float | None`` of ``<directory>/<metric>.py``."""
-    path = directory / f"{metric}.py"
-    key = f"bench_layer_{metric.replace('.', '_')}"
-    spec = importlib.util.spec_from_file_location(key, path)
-    mod = sys.modules[key] = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_file(directory / f"{metric}.py",
+                     f"bench_layer_{metric.replace('.', '_')}").read
+
+
+# the modules a configuration file names, by key, and what each provides:
+#   reference  gaps(config, seed, seqs, control=False) -> max_gap, mean_gap,
+#              tokens, per_seq, and with ``control`` the control's readings
+#   work       weight_bytes(config) of the served tree; decode_step(config,
+#              contexts) -> (flops, bytes) of one decode step
+CONFIG_MODULES = {"reference": ("gaps",),
+                  "work": ("weight_bytes", "decode_step")}
+
+
+def config_module(config: dict, key: str,
+                  root: pathlib.Path = ROOT) -> types.ModuleType:
+    """The module that the configuration file names under ``key``, a path
+    from the checkout's ``root``; a file that is missing or lacks one of
+    ``CONFIG_MODULES[key]`` stops the run, naming it."""
+    rel = config.get(key)
+    where = f"configuration {config.get('name')!r}"
+    if not isinstance(rel, str):
+        raise SystemExit(f"{where} names no {key!r} file")
+    path = (root / rel).resolve()
+    if not path.is_relative_to(root.resolve()) or not path.is_file():
+        raise SystemExit(f"{where}: {key} {rel} is not a file of the "
+                         f"checkout {root}")
+    mod = load_file(path, f"bench_{key}_{re.sub(r'[^0-9A-Za-z]', '_', rel)}")
+    missing = [f for f in CONFIG_MODULES[key]
+               if not callable(getattr(mod, f, None))]
+    if missing:
+        raise SystemExit(f"{where}: {key} {rel} has no {', '.join(missing)}")
+    return mod
 
 
 def require_chips(chips: int):
@@ -166,9 +208,17 @@ def serve_args(config: dict, seed: int, annotate: bool):
     return serve.build_parser().parse_args(argv)
 
 
+def _hashable(v):
+    return tuple(map(_hashable, v)) if isinstance(v, list) else v
+
+
 def model_config(config: dict):
     """The program's ArchConfig of the architecture named in ``serving``,
-    with every size the file holds (the file is the configuration as run)."""
+    with every size the file holds (the file is the configuration as run):
+    the ten keys of the table below, then the fields the file's optional
+    ``arch_fields`` sets by their ArchConfig names (experts, layer kinds,
+    state sizes: what the table does not hold).  A JSON list there becomes
+    a tuple, so the config stays hashable as a static argument."""
     from repro.configs import base as cb
     sizes = {"n_layers": "num_hidden_layers", "d_model": "hidden_size",
              "d_ff": "intermediate_size", "n_heads": "num_attention_heads",
@@ -176,8 +226,21 @@ def model_config(config: dict):
              "vocab": "vocab_size", "rope_theta": "rope_theta",
              "norm_eps": "rms_norm_eps",
              "tie_embeddings": "tie_word_embeddings"}
-    return dataclasses.replace(cb.get(config["serving"]["arch"]),
-                               **{a: config[b] for a, b in sizes.items()})
+    fields = config.get("arch_fields", {})
+    known = {f.name for f in dataclasses.fields(cb.ArchConfig)}
+    for k in fields:
+        if k not in known:
+            raise SystemExit(f"configuration {config.get('name')!r}: "
+                             f"arch_fields sets {k!r}, which ArchConfig "
+                             f"has no field of")
+        if k in sizes:
+            raise SystemExit(f"configuration {config.get('name')!r}: "
+                             f"arch_fields sets {k!r}, which the file "
+                             f"holds as {sizes[k]!r}")
+    cfg = dataclasses.replace(cb.get(config["serving"]["arch"]),
+                              **{a: config[b] for a, b in sizes.items()})
+    return dataclasses.replace(
+        cfg, **{k: _hashable(v) for k, v in fields.items()})
 
 
 def weights_seed(seed: int) -> int:
@@ -185,7 +248,10 @@ def weights_seed(seed: int) -> int:
     return seed % (2 ** 31 - 1)
 
 
-def build(config: dict, seed: int, annotate: bool = False):
+def build(config: dict, seed: int, counts: types.ModuleType,
+          annotate: bool = False):
+    """The engine with its served weights; ``counts`` is the module the
+    configuration names under ``work``."""
     import jax
     from repro.launch import serve
     from repro.serve.engine import Engine
@@ -194,7 +260,8 @@ def build(config: dict, seed: int, annotate: bool = False):
     params = jax.block_until_ready(serve.build_engine_params(cfg, sargs))
     n_bytes = sum(x.nbytes for x in jax.tree.leaves(params))
     log(f"[build] {cfg.n_layers} layers W{config['serving']['w_bits']}: "
-        f"{n_bytes} B served (work.py counts {work.weight_bytes(config)})")
+        f"{n_bytes} B served ({config['work']} counts "
+        f"{counts.weight_bytes(config)})")
     return Engine(params, cfg, serve.SERVE_OPTS, serve.engine_config(sargs))
 
 
@@ -562,7 +629,7 @@ def main(argv=None, require=require_chips,
         f"compile cache {enable_compile_cache()}")
     config, mix = cell.config, cell.mix
     control = bool(args.control)
-    eng = build(config, args.seed, annotate=bool(args.trace))
+    eng = build(config, args.seed, cell.work, annotate=bool(args.trace))
     n_warm = warm(eng, config, mix, args.seed)
     drv = Driver(eng, config, mix, args.seed, cell.generate)
     t_setup = time.perf_counter()
@@ -598,7 +665,7 @@ def main(argv=None, require=require_chips,
         reading = types.SimpleNamespace(
             trace=tr, window=w, window_s=w[1] - w[0], drv=drv, win=win,
             spans=list(eng.telemetry.tracer.spans()), config=config,
-            peaks=work.peaks(devs[0].device_kind))
+            work=cell.work, peaks=work.peaks(devs[0].device_kind))
         for m in cell.per_layer:
             v = layer_reader(m["name"], root / "bench" / "layers")(reading)
             if v is not None:
@@ -625,14 +692,14 @@ def main(argv=None, require=require_chips,
     seqs = check_sample(drv, mix["check_requests"], args.seed)
     del eng, drv.eng
     gc.collect()
-    from bench import reference
     t_ref = time.perf_counter()
-    got = reference.gaps(config, weights_seed(args.seed), seqs,
-                         control=control)
+    got = cell.reference.gaps(config, weights_seed(args.seed), seqs,
+                              control=control)
     number = chk["number"]
-    log(f"[check] reference over {len(seqs)} requests, {got['tokens']} "
-        f"served tokens, in {time.perf_counter() - t_ref:.1f}s; per request "
-        f"widest gap {['%.4f' % g for g in got['per_seq']]}; widest "
+    log(f"[check] reference {config['reference']} over {len(seqs)} "
+        f"requests, {got['tokens']} served tokens, in "
+        f"{time.perf_counter() - t_ref:.1f}s; per request widest gap "
+        f"{['%.4f' % g for g in got['per_seq']]}; widest "
         f"{got['max_gap']!r}, mean {got['mean_gap']!r}")
     value = got[number]
     if control:             # the control is put in the program's place

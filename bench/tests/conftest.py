@@ -57,6 +57,9 @@ def smoke_root(tmp_path_factory):
     (root / "bench" / "configs").mkdir(parents=True)
     (root / "bench" / "traffic").mkdir()
     shutil.copytree(BENCH / "layers", root / "bench" / "layers")
+    # the modules the configuration files name, found in the checkout
+    for name in ("reference.py", "work.py"):
+        shutil.copy(BENCH / name, root / "bench" / name)
     configs = {
         "smoke.w4kv8": _shrink_config(
             "smoke.w4kv8", dict(w_bits=4, kv_bits=8, max_slots=4, max_len=128,

@@ -46,7 +46,8 @@ def main(out_dir: str) -> None:
     config = json.loads((run.ROOT / conf[SOURCE_CONFIG]["file"]).read_text())
     config.update(SMOKE_SIZES)
     config["serving"].update(SERVING)
-    eng = run.build(config, seed=3, annotate=True)
+    eng = run.build(config, seed=3, counts=run.config_module(config, "work"),
+                    annotate=True)
     run.warm(eng, config, None, seed=3)
     rng = np.random.default_rng(3)
 

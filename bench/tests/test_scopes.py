@@ -10,7 +10,8 @@ import types
 
 import pytest
 
-from bench import run, scopes, trace
+from bench import run, scopes, trace, work
+from bench.tests.conftest import SMOKE_SIZES, SOURCE_CONFIG, config_file
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
 READERS = ("decode_attn_ms", "decode_matmul_ms", "decode_unscoped_ms",
@@ -180,3 +181,41 @@ def test_recorded_readers_read_the_same_without_python_frames(recorded, name):
                                           counts["prefill_tokens"]))
                      for t in (tr, bare))
     assert full is not None and without == full
+
+
+class _Doubled:
+    """A counts module of another architecture: twice the dense counts."""
+    weight_bytes = staticmethod(work.weight_bytes)
+
+    @staticmethod
+    def decode_step(c, contexts):
+        flops, nbytes = work.decode_step(c, contexts)
+        return 2 * flops, 2 * nbytes
+
+
+@pytest.mark.parametrize("scale", [1, 2], ids=["granite_file", "doubled"])
+def test_recorded_decode_mfu_reads_the_configurations_counts(recorded,
+                                                             scale):
+    """``decode_mfu`` takes a step's operations and bytes from ``r.work``,
+    the module the configuration names: the Granite file's reads what
+    ``bench/work.py`` gives, and a module that counts twice the work reads
+    twice the share."""
+    from bench.tests.record_trace import SERVING
+    tr, scope_map, counts = recorded
+    config = json.loads(config_file(SOURCE_CONFIG).read_text())
+    config.update(SMOKE_SIZES)
+    config["serving"].update(SERVING)
+    r = _reading(tr, scope_map, counts["decode_steps"],
+                 counts["prefill_tokens"])
+    # the recording kept no contexts: three rows, a fourth joining
+    steps = [[21 + i] * 3 for i in range(4)] + \
+        [[25 + i] * 3 + [13 + i] for i in range(4)]
+    mid = (r.window[0] + r.window[1]) / 2
+    r.drv.decode_ctx = [(mid, ctx) for ctx in steps]
+    r.win.start, r.win.end = r.window
+    r.config, r.peaks = config, work.peaks("TPU v5 lite")
+    r.work = run.config_module(config, "work") if scale == 1 else _Doubled
+    dev = trace.module_seconds(tr, r.window)["jit_decode_paged"]
+    dense = 100.0 * sum(work.least_seconds(*work.decode_step(config, ctx),
+                                           r.peaks)[0] for ctx in steps) / dev
+    assert _read("decode_mfu", r) == pytest.approx(scale * dense, rel=1e-12)
